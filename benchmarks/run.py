@@ -827,6 +827,9 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     if args.smoke:
         args.quick = True
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     rows = []
     benches = {

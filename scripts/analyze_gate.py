@@ -54,7 +54,7 @@ def check_seeded_violations() -> None:
     import jax.numpy as jnp
 
     # f64 promotion: must surface in BOTH the jaxpr walk and the HLO scan
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         prog = jax.jit(lambda x: x.astype(jnp.float64) * 2.0)
         args = (jax.ShapeDtypeStruct((8, 8), jnp.float32),)
         findings = lint_program(prog, args, "selftest:f64")

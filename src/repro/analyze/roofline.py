@@ -188,8 +188,6 @@ def analyze_compiled(
 
     stats = analyze_hlo_text(compiled.as_text())
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0]
     mem = None
     try:
         ma = compiled.memory_analysis()

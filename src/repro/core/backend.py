@@ -109,6 +109,16 @@ def _next_pow2(n: int) -> int:
     return 1 << max(0, (int(n) - 1).bit_length())
 
 
+def _resolve_interpret(interpret: Union[bool, None]) -> bool:
+    """A kernel backend's ``interpret`` toggle; None = interpret only on
+    the CPU platform (``kernels.ops.use_interpret``)."""
+    if interpret is None:
+        from ..kernels.ops import use_interpret
+
+        return use_interpret()
+    return interpret
+
+
 # ----------------------------------------------------------- semiring ops
 
 
@@ -222,14 +232,32 @@ class ParserBackend:
     name: str = "abstract"
     min_lane_pad: int = 32   # segment-dim alignment this backend requires
 
+    @property
+    def max_ell_pad(self) -> Union[int, None]:
+        """Largest ℓp this backend's Pallas kernels compile for on a TPU
+        v5e core (its scoped VMEM), or None when it runs no kernel."""
+        return None
+
+    def check_ell_pad(self, ell_pad: int) -> None:
+        """Refuse an automaton wider than the kernels compile for — at
+        construction, never by falling back to another path."""
+        limit = self.max_ell_pad
+        if limit is not None and ell_pad > limit:
+            raise ValueError(
+                f"backend {self.name!r} runs Pallas kernels that compile for "
+                f"ℓp ≤ {limit} on a TPU v5e (scoped VMEM); this automaton "
+                f"has ℓp={ell_pad}"
+            )
+
     def bind_tables(self, tables) -> None:
         """One-time hook: the concrete ``EngineTables`` this backend will run.
 
         Called by ``ParserEngine.__init__`` before any phase program is
         traced.  Backends whose product representation depends on the
         automaton (the sparse width bucket S) derive their static shapes
-        here; the default is a no-op — most backends are table-agnostic.
+        here; the default only checks ℓp against the kernels' limit.
         """
+        self.check_ell_pad(int(tables.ell_pad))
 
     def reach(self, N: jnp.ndarray, chunks: jnp.ndarray) -> jnp.ndarray:
         """(c, k) chunks → stacked chunk products (axis 0 = chunk)."""
@@ -325,9 +353,9 @@ class JnpBackend(ParserBackend):
 class PallasBackend(ParserBackend):
     """Mosaic kernels for the two hot loops (scalar-prefetch DMA pipelining).
 
-    ``interpret=None`` auto-selects: real Mosaic on TPU, interpret mode on CPU
-    (kernel bodies run under the Pallas interpreter, validating BlockSpecs and
-    index maps — the CI-checkable form of the TPU program).  Chunk and batch
+    ``interpret=None`` auto-selects: interpret mode on the CPU (kernel bodies
+    run under the Pallas interpreter, validating BlockSpecs and index maps —
+    the CI-checkable form of the TPU program), real Mosaic everywhere else.  Chunk and batch
     axes run under ``lax.map``: the kernels own the intra-chunk grid, and the
     sequential outer loop keeps a single (ℓp, ℓp) VMEM working set live.
     """
@@ -338,12 +366,14 @@ class PallasBackend(ParserBackend):
     def __init__(self, interpret: Union[bool, None] = None):
         self.interpret = interpret
 
-    def _interp(self) -> bool:
-        if self.interpret is None:
-            from ..kernels.ops import use_interpret
+    @property
+    def max_ell_pad(self) -> int:
+        from ..kernels import build, reach
 
-            return use_interpret()
-        return self.interpret
+        return min(reach.MAX_ELL_PAD, build.MAX_ELL_PAD)
+
+    def _interp(self) -> bool:
+        return _resolve_interpret(self.interpret)
 
     def reach(self, N, chunks):
         from ..kernels.reach import reach_chunk_product
@@ -392,7 +422,7 @@ class PackedBackend(ParserBackend):
     real-TPU tuning item (ROADMAP).
 
     ``kernel=True`` routes reach through the Pallas packed OR-AND kernel
-    (``kernels/packed_reach.py``; interpret mode off-TPU) instead of the
+    (``kernels/packed_reach.py``; interpret mode on the CPU) instead of the
     XLA word ops — the TPU-experiment path, bit-identical by test.
     """
 
@@ -403,13 +433,20 @@ class PackedBackend(ParserBackend):
         self.kernel = kernel
         self.interpret = interpret
 
+    @property
+    def max_ell_pad(self) -> Union[int, None]:
+        if not self.kernel:
+            return None
+        from ..kernels.packed_reach import MAX_ELL_PAD
+
+        return MAX_ELL_PAD
+
     def reach(self, N, chunks):
         Np = pack_transition_table_jnp(N)            # (A+1, ℓp, W)
         if self.kernel:
-            from ..kernels.ops import use_interpret
             from ..kernels.packed_reach import packed_reach_chunk_product
 
-            interp = use_interpret() if self.interpret is None else self.interpret
+            interp = _resolve_interpret(self.interpret)
             return jax.lax.map(
                 lambda ch: packed_reach_chunk_product(Np, ch, interpret=interp),
                 chunks,
@@ -459,17 +496,17 @@ class PackedBackend(ParserBackend):
 
             _, fwd = jax.lax.scan(fstep, pack_bits_jnp(ef), chunk)
 
-            ebp = pack_bits_jnp(eb)
+            # backward pass fused with the merge, visiting t = k-1 … 0 with
+            # β_{t+1} carried (β_k = entry_b): M[t] = fwd[t] ∧ β_{t+1}, one
+            # word-AND, then β_t = N[x_t]ᵀ β_{t+1}.  No reversed or shifted
+            # copy of the (k, W) state stack is made: at k = 2¹⁸ the TPU
+            # compiler's program for that form returned wrong columns.
+            def bstep(vp, xs):
+                cls, f = xs
+                return packed_matvec_T_words(Np[cls], vp), f & vp
 
-            def bstep(vp, cls):
-                nvp = packed_matvec_T_words(Np[cls], vp)
-                return nvp, nvp
-
-            _, bwd_rev = jax.lax.scan(bstep, ebp, chunk[::-1])
-            bwd = bwd_rev[::-1]                      # β₀ … β_{k-1} packed words
-            # merge: M[t] = fwd[t] ∧ β_{t+1};  β_k = entry_b — one word-AND
-            bwd_next = jnp.concatenate([bwd[1:], ebp[None]], axis=0)
-            return fwd & bwd_next                    # (k, W) packed columns
+            _, M = jax.lax.scan(bstep, pack_bits_jnp(eb), (chunk, fwd), reverse=True)
+            return M                                 # (k, W) packed columns
 
         return jax.vmap(one)(chunks, Jf, Jb)
 
@@ -515,8 +552,8 @@ class SparseBackend(PackedBackend):
     build&merge keep the contract's fixed f32/u32 seams (build&merge is
     entry-driven and inherits the packed word path unchanged).
 
-    ``kernel=True`` routes the gathered-row fold through the Pallas kernel
-    (``kernels/sparse_reach.py``; interpret mode off-TPU).  ``depth`` is the
+    ``kernel=True`` routes the gathered-row fold through the packed Pallas
+    kernel (``kernels/packed_reach.py``; interpret mode on the CPU).  ``depth`` is the
     feasible-prefix depth: characters of the chunk consulted when pruning
     (``ParserConfig.feasible_depth``); deeper prunes harder at the cost of
     d sequential mat-vecs before the fold.
@@ -564,6 +601,7 @@ class SparseBackend(PackedBackend):
         rule as ``bind_tables``.
         """
         lp = int(ell_pad)
+        self.check_ell_pad(lp)
         S = _next_pow2(max(self.min_width, int(raw_width), 1))
         # dense-fallback rule: no reduction to be had → carry every row
         self._width = lp if S >= lp else S
@@ -592,10 +630,9 @@ class SparseBackend(PackedBackend):
         depth = min(self.depth, chunks.shape[-1])
         ident = sparse_identity(S, lp // 32)
         if self.kernel:
-            from ..kernels.ops import use_interpret
-            from ..kernels.sparse_reach import sparse_reach_rows
+            from ..kernels.packed_reach import packed_fold_rows
 
-            interp = use_interpret() if self.interpret is None else self.interpret
+            interp = _resolve_interpret(self.interpret)
 
         def feasible_idx(chunk):
             u = jnp.ones((lp,), N.dtype)
@@ -613,7 +650,7 @@ class SparseBackend(PackedBackend):
             idx = feasible_idx(chunk)
             R0 = sparse_init_rows(idx, lp)           # (S, W) packed e_idx rows
             if self.kernel:
-                R = sparse_reach_rows(Np, chunk, R0, interpret=interp)
+                R = packed_fold_rows(Np, chunk, R0, interpret=interp)
             else:
                 def step(R, cls):
                     return (

@@ -67,7 +67,6 @@ PAD rows/chunks are semantics-free, so divisibility padding is free).
 
 from __future__ import annotations
 
-import functools
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -80,15 +79,6 @@ from ..parallel.sharding import MeshRules, spec_axes
 from .engine import _next_pow2, join_with_col0, _resolve_engine
 from .scan import linear_index
 from .slpf import SLPF
-
-
-def _shard_map():
-    """jax.shard_map across jax versions (legacy: experimental, check_rep)."""
-    if hasattr(jax, "shard_map"):  # jax ≥ 0.6
-        return functools.partial(jax.shard_map, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _esm
-
-    return functools.partial(_esm, check_rep=False)
 
 
 def _entry(axes: Tuple[str, ...]):
@@ -211,9 +201,10 @@ class DistributedEngine:
             M = backend.build_merge_packed(N, chunks, Jf_loc, Jb_loc)
             return col0p, M
 
-        program = _shard_map()(
+        program = jax.shard_map(
             body,
             mesh=self.mesh,
+            check_vma=False,
             in_specs=(PartitionSpec(), PartitionSpec(), PartitionSpec(), spec),
             out_specs=(PartitionSpec(), spec),
         )
@@ -259,9 +250,10 @@ class DistributedEngine:
             M = bm_b(batch, Jf_loc, Jb_loc)               # (B_loc, c_loc, k, W)
             return col0p, M
 
-        program = _shard_map()(
+        program = jax.shard_map(
             body,
             mesh=self.mesh,
+            check_vma=False,
             in_specs=(PartitionSpec(), PartitionSpec(), PartitionSpec(), spec_in),
             out_specs=(spec_b, spec_in),
         )
@@ -293,9 +285,10 @@ class DistributedEngine:
             P_all = _gather(P, axes, axis=0)
             return join_with_col0(backend, P_all, I, F)
 
-        program = _shard_map()(
+        program = jax.shard_map(
             body,
             mesh=self.mesh,
+            check_vma=False,
             in_specs=(spec, PartitionSpec(), PartitionSpec()),
             out_specs=(PartitionSpec(), PartitionSpec(), PartitionSpec()),
         )

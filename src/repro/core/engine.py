@@ -414,6 +414,22 @@ class ParserEngine:
         columns = unpack_bits(packed, self.tables.ell, axis=-1)
         return SLPF(table=self.table, columns=columns, classes=classes)
 
+    def lower_batch(self, B: int, c: int, k: int, *, sharding=None):
+        """Lower the fused batch program of a (B, c, k) bucket without
+        running it — for compile checks and compiled-HLO inspection.
+        ``sharding`` places every operand (e.g. on a described device)."""
+        t = self.tables
+
+        def spec(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        return self._jit_batched.lower(
+            spec(t.N.shape, t.N.dtype),
+            spec(t.I.shape, t.I.dtype),
+            spec(t.F.shape, t.F.dtype),
+            spec((B, c, k), jnp.int32),
+        )
+
     # -------------------------------------------------------- observability
 
     def parse_traced(self, text, n_chunks: int = 8) -> SLPF:

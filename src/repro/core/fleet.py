@@ -458,6 +458,7 @@ class FleetEngine:
         runner = self._buckets.get(key)
         if runner is None:
             backend = spec.make_backend()
+            backend.check_ell_pad(ct.ell_pad)
             if isinstance(backend, SparseBackend):
                 backend.bind_shape(ct.ell_pad, ct.width_bound)
             runner = _BucketRunner(key, backend, self.obs, self._bump_compiles)

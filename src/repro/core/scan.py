@@ -93,18 +93,11 @@ def _all_gather_multi(x: jnp.ndarray, axis_names: Sequence[str]) -> jnp.ndarray:
     return g
 
 
-def axis_size(name: str):
-    """``jax.lax.axis_size`` with a fallback for older jax (psum of 1)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
 def linear_index(axis_names: Sequence[str]) -> jnp.ndarray:
     """This device's linear position over possibly-multiple mesh axes."""
     idx = jnp.int32(0)
     for name in axis_names:
-        idx = idx * axis_size(name) + jax.lax.axis_index(name)
+        idx = idx * jax.lax.axis_size(name) + jax.lax.axis_index(name)
     return idx
 
 

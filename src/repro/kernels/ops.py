@@ -1,9 +1,10 @@
 """Public jit'd wrappers for the parser kernels.
 
-On CPU (this container) the kernels execute with ``interpret=True`` — the
-kernel body runs in Python per grid step, validating the BlockSpec tiling and
-index maps against the pure-jnp oracles.  On TPU backends the same calls lower
-to Mosaic.  ``use_interpret()`` picks automatically.
+On the CPU the kernels execute with ``interpret=True`` — the kernel body
+runs in Python per grid step, validating the BlockSpec tiling and index maps
+against the pure-jnp oracles.  On every other platform the same calls lower
+to Mosaic, so a kernel that cannot compile there fails instead of silently
+running interpreted.  ``use_interpret()`` picks automatically.
 """
 
 from __future__ import annotations
@@ -31,7 +32,8 @@ from .ref import (
 
 
 def use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret the kernels only on the CPU platform."""
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk"))

@@ -10,8 +10,8 @@ from HBM into VMEM while the current product runs on the MXU (the classic
 lookahead the paper's table-walk cannot express).  The running product lives
 in a VMEM scratch across grid steps.
 
-For ℓ ≤ ~1024 an (ℓ, ℓ) fp32 tile fits VMEM (1024²·4 = 4 MiB); larger
-automata shard the segment dimension over 'model' (engine) before kerneling.
+The (ℓ, ℓ) f32 blocks — N double-buffered, the running product and the
+output — fit a v5e core's scoped VMEM up to ``MAX_ELL_PAD``.
 """
 
 from __future__ import annotations
@@ -22,6 +22,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+# Largest ℓp (a multiple of 128) that compiles within a TPU v5e core's
+# scoped VMEM; ℓp = 1024 runs out
+MAX_ELL_PAD = 896
 
 
 def _reach_kernel(ids_ref, n_ref, out_ref, acc_ref, *, k: int):

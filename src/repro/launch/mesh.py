@@ -13,25 +13,13 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-
-try:  # jax ≥ 0.5: explicit axis types on mesh construction
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto
-    AxisType = None
-
-
-def make_mesh_compat(shape, axes, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types across jax versions."""
-    kwargs = {} if devices is None else {"devices": devices}
-    if AxisType is not None:
-        kwargs["axis_types"] = (AxisType.Auto,) * len(axes)
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(shape: Tuple[int, ...] = (1,), axes: Tuple[str, ...] = ("data",)):
@@ -42,7 +30,7 @@ def make_host_mesh(shape: Tuple[int, ...] = (1,), axes: Tuple[str, ...] = ("data
     avail = len(jax.devices())
     if n > avail:
         shape, axes = (avail,), ("data",)
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_parse_mesh(*, max_pods: int = 2):
@@ -54,7 +42,9 @@ def make_parse_mesh(*, max_pods: int = 2):
     still run, with no collectives resident)."""
     n = len(jax.devices())
     pods = max_pods if n >= max_pods and n % max_pods == 0 else 1
-    return make_mesh_compat((pods, n // pods), ("pod", "data"))
+    return jax.make_mesh(
+        (pods, n // pods), ("pod", "data"), axis_types=(AxisType.Auto,) * 2
+    )
 
 
 def mesh_axes_size(mesh, axes) -> int:

@@ -373,7 +373,7 @@ def test_lint_catches_seeded_f64():
     import jax
     import jax.numpy as jnp
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         prog = jax.jit(lambda x: x.astype(jnp.float64) * 2.0)
         findings = lint_program(
             prog, (jax.ShapeDtypeStruct((4, 4), jnp.float32),), "t:f64"

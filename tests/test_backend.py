@@ -144,3 +144,40 @@ def test_pallas_lane_pad_floor(art):
     """The pallas backend forces the kernels' 128-lane MXU alignment."""
     eng = ParserEngine(art.matrices, backend="pallas", lane_pad=32)
     assert eng.tables.ell_pad % 128 == 0
+
+
+@pytest.mark.parametrize(
+    "backend",
+    [PallasBackend(), PackedBackend(kernel=True), SparseBackend(kernel=True)],
+    ids=["pallas", "packed+kernel", "sparse+kernel"],
+)
+def test_kernel_backend_refuses_wider_than_its_kernels(backend):
+    """A kernel backend checks ℓp against what its kernels compile for."""
+    limit = backend.max_ell_pad
+    backend.check_ell_pad(limit)
+    with pytest.raises(ValueError, match=f"ℓp ≤ {limit}"):
+        backend.check_ell_pad(limit + 128)
+
+
+@pytest.mark.parametrize("backend", ["jnp", "packed", "sparse"])
+def test_xla_backends_have_no_kernel_width_limit(backend):
+    b = get_backend(backend)
+    assert b.max_ell_pad is None
+    b.check_ell_pad(1 << 16)
+
+
+def test_wide_automaton_refused_when_the_parser_is_built():
+    """e(445) has ℓ = 897 > the reach kernel's 896: construction raises
+    rather than falling back to another path."""
+    from repro import Parser, ParserConfig
+    from repro.core.segments import compute_segments
+
+    table = compute_segments("(a|b)*a(a|b){445}")
+    with pytest.raises(ValueError, match="pallas"):
+        Parser.from_matrices(
+            table, ParserConfig(regex="<e445>", backend="pallas", analyze="off")
+        )
+    p = Parser.from_matrices(
+        table, ParserConfig(regex="<e445>", backend="packed", kernel=True, analyze="off")
+    )
+    assert p.engine.tables.ell_pad == 928

@@ -72,9 +72,9 @@ def test_elastic_restore_onto_sharding(tmp_path):
     m = CheckpointManager(tmp_path)
     t = _tree()
     m.save(2, t)
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
 
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), t)
     step, got, _ = m.restore(t, shardings=sh)
     assert step == 2
@@ -100,8 +100,9 @@ from repro.train.checkpoint import CheckpointManager
 
 tree = {{"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
         "emb": jnp.arange(32, dtype=jnp.bfloat16).reshape(16, 2)}}
-from repro.launch.mesh import make_mesh_compat
-mesh_a = make_mesh_compat((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+AUTO2 = (AxisType.Auto,) * 2
+mesh_a = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO2)
 sh_a = {{"w": NamedSharding(mesh_a, P("data", "model")),
         "emb": NamedSharding(mesh_a, P("data", None))}}
 placed = jax.tree.map(lambda t, s: jax.device_put(t, s), tree, sh_a)
@@ -109,7 +110,7 @@ m = CheckpointManager(r"{tmp_path}", keep=2)
 m.save(1, placed)
 
 # restore on a different topology
-mesh_b = make_mesh_compat((4, 2), ("data", "model"))
+mesh_b = jax.make_mesh((4, 2), ("data", "model"), axis_types=AUTO2)
 sh_b = {{"w": NamedSharding(mesh_b, P("model", "data")),
         "emb": NamedSharding(mesh_b, P(None, "model"))}}
 step, got, _ = m.restore(tree, shardings=sh_b)
@@ -117,7 +118,9 @@ for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(got)):
     np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
 
 # restore on a smaller world (2 devices) — node-loss scenario
-mesh_c = make_mesh_compat((2,), ("data",), devices=jax.devices()[:2])
+mesh_c = jax.make_mesh(
+    (2,), ("data",), axis_types=(AxisType.Auto,), devices=jax.devices()[:2]
+)
 sh_c = jax.tree.map(lambda _: NamedSharding(mesh_c, P("data")), tree)
 step, got2, _ = m.restore(tree, shardings=sh_c)
 for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(got2)):

@@ -15,6 +15,7 @@ import sys
 from pathlib import Path
 
 import jax
+from jax.sharding import AxisType
 import numpy as np
 import pytest
 
@@ -24,7 +25,7 @@ from repro.core.engine import ParserEngine
 from repro.core.reference import ParallelArtifacts
 from repro.core.serial import parse_serial_matrix
 from repro.core.stream import StreamingParser
-from repro.launch.mesh import make_mesh_compat, make_parse_mesh
+from repro.launch.mesh import make_parse_mesh
 from repro.serve.parse_service import ParseService
 
 AMBIG = "(a|b|ab)+"
@@ -48,7 +49,9 @@ def ref_engine(art):
 
 
 def _mesh_8():
-    return make_mesh_compat((2, 4), ("pod", "data"))
+    return jax.make_mesh(
+        (2, 4), ("pod", "data"), axis_types=(AxisType.Auto,) * 2
+    )
 
 
 # ------------------------------------------------------------ legacy gone
@@ -221,8 +224,8 @@ from repro.core.reference import ParallelArtifacts
 from repro.core.serial import parse_serial_matrix
 from repro.core.engine import ParserEngine
 from repro.core.stream import StreamingParser
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ("pod", "data"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
 art = ParallelArtifacts.generate("(a|b|ab)+")
 ref = ParserEngine(art.matrices)
 eng = ParserEngine(art.matrices, mesh=mesh)
