@@ -2,21 +2,25 @@
 
     PYTHONPATH=src python examples/traced_parse.py [--smoke]
 
-The paper's cost model attributes parallel parse time to phases — chunk
-reach, the associative join, build&merge — and the serving stack adds two
-more buckets: queue wait and batched device compute.  This example turns on
-the observability layer (ROADMAP "Observability") and shows all of it
-through the supported surface only:
+A parse's time goes to the service queue, the host's preparation of the
+padded chunk grid, the fused device program, the copy of its packed
+columns back to the host, the host's assembly of the forest, and the
+speculation-width report.  The paper's cost model splits the device part
+further by phase — chunk reach, the associative join, build&merge.  This
+example turns on the observability layer (ROADMAP "Observability") and
+shows both splits through the supported surface:
 
   * ``ParserConfig(obs=ObsConfig(enabled=True, span_log=...))`` — tracing
     on, spans mirrored to a JSONL file;
-  * a direct ``parse`` (phase-split spans: reach / join / build&merge /
-    host build) and a ``submit`` → ticket round trip (queue-wait +
-    batch-compute spans), both carrying a ``trace_id`` on the result;
-  * the span tree, validated and pretty-printed from the JSONL log;
-  * ``Parser.stats()`` as a metrics view: cataloged counters/gauges, the
-    per-bucket queue/compute p50/p99 split, and the static HLO cost of
-    each compiled phase program;
+  * a direct ``parse`` (with tracing on it runs the phase-split programs:
+    reach / join / build&merge / host build spans) and a ``submit`` →
+    ticket round trip (the fused route: queue wait, batch compute over
+    host prep / device / fetch / assembly), both with a speculation span
+    and a ``trace_id`` on the result;
+  * the span trees, validated and pretty-printed from the JSONL log;
+  * ``Parser.stats()`` as a metrics view: cataloged counters/gauges (padded
+    and text cells per bucket, device-to-host bytes) and the per-bucket
+    queue/compute p50/p99 split;
   * the Prometheus rendering of the same registry.
 """
 
@@ -35,8 +39,15 @@ def print_tree(spans, trace_id):
     root = tree["root"]
     print(f"  trace {trace_id}  root={root['name']}  "
           f"{root['duration_s'] * 1e3:8.2f} ms  attrs={root['attrs']}")
-    for c in sorted(tree["children"], key=lambda s: s["t_start_s"]):
-        print(f"    └─ {c['name']:<24s} {c['duration_s'] * 1e3:8.2f} ms")
+
+    def walk(parent, depth):
+        kids = [s for s in tree["children"] if s["parent_id"] == parent["span_id"]]
+        for c in sorted(kids, key=lambda s: s["t_start_s"]):
+            print(f"    {'   ' * depth}└─ {c['name']:<24s} "
+                  f"{c['duration_s'] * 1e3:8.2f} ms")
+            walk(c, depth + 1)
+
+    walk(root, 0)
 
 
 def main() -> None:
@@ -59,7 +70,7 @@ def main() -> None:
         print(f"parse ok={direct.ok} backend={direct.backend} "
               f"bucket={direct.bucket} trace_id={direct.trace_id}")
 
-        # service route: queue-wait vs batch-compute attribution
+        # service route: queue wait, then the fused program's batch
         tickets = [parser.submit("ab" * n) for n in (8, 16, 24)]
         served = [t.result() for t in tickets]
         print(f"served {len(served)} tickets "
@@ -69,7 +80,7 @@ def main() -> None:
         print(f"\nspan log: {len(spans)} spans in {span_log}")
         print("\ndirect route (phase attribution):")
         print_tree(spans, direct.trace_id)
-        print("\nticket route (queue vs compute):")
+        print("\nticket route (queue wait, then the fused program):")
         print_tree(spans, served[0].trace_id)
 
         stats = parser.stats()
@@ -79,14 +90,12 @@ def main() -> None:
                   f"p99_queue={d['p99_queue_s'] * 1e3:.2f} ms "
                   f"p99_compute={d['p99_compute_s'] * 1e3:.2f} ms")
 
-        print("\nstatic HLO cost per compiled bucket (flops / bytes):")
-        for bucket, phases in (stats["hlo"] or {}).items():
-            t = phases["total"]
-            print(f"  bucket {bucket}: {t['flops']:.3g} flops, "
-                  f"{t['bytes']:.3g} bytes "
-                  f"(reach {phases['reach']['flops']:.3g}, "
-                  f"join {phases['join']['flops']:.3g}, "
-                  f"build&merge {phases['build_merge']['flops']:.3g})")
+        print("\ncells the fused program ran, per bucket (text / padding):")
+        metrics = stats["metrics"]
+        for text, pad in zip(metrics["text_cells_total"],
+                             metrics["padded_cells_total"]):
+            print(f"  bucket {text['labels']['bucket']}: "
+                  f"{text['value']:.0f} text, {pad['value']:.0f} padding")
 
         print("\nprometheus exposition (first 12 lines):")
         for line in prometheus_text(stats["metrics"]).splitlines()[:12]:

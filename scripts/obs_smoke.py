@@ -10,8 +10,10 @@ Four checks, all through the public facade (``repro.Parser`` with
      JSONL log (one root, parents resolve, child durations bounded by the
      root: ``validate_span_tree``);
   2. the span taxonomy holds — ``parse.request`` roots with phase children
-     (reach/join/build&merge) on the direct route, queue-wait + batch-compute
-     children on the ticket route;
+     (reach/join/build&merge/host build) on the direct route, which runs
+     ``ParserEngine.parse_phase_split``; queue-wait and batch-compute
+     children, the batch compute over host prep / device / fetch /
+     assembly, on the ticket route; speculation on both;
   3. metric-name rot guard — every name in every registry snapshot is in
      ``METRIC_CATALOG`` (``validate_metric_names``), and ``prometheus_text``
      renders the snapshot;
@@ -52,6 +54,9 @@ from repro.obs import (
 
 PHASE_SPANS = {"phase.reach", "phase.join", "phase.build_merge",
                "phase.host_build"}
+REQUEST_SPANS = {"parse.queue_wait", "parse.batch_compute", "parse.speculation"}
+FUSED_SPANS = {"parse.host_prep", "parse.device", "parse.fetch",
+               "parse.assemble"}
 
 
 def check_backend(backend: str, workdir: Path) -> None:
@@ -87,8 +92,8 @@ def check_backend(backend: str, workdir: Path) -> None:
             f"{backend}/{route}: root span is {root['name']!r}"
         children = {s["name"] for s in spans
                     if s["trace_id"] == tid and s["parent_id"] is not None}
-        want = (PHASE_SPANS if route == "direct"
-                else {"parse.queue_wait", "parse.batch_compute"})
+        want = (PHASE_SPANS | {"parse.speculation"} if route == "direct"
+                else REQUEST_SPANS | FUSED_SPANS)
         missing = want - children
         assert not missing, f"{backend}/{route}: missing spans {sorted(missing)}"
     print(f"ok: {backend:7s} — {len(spans)} spans, both routes form valid trees")

@@ -144,8 +144,7 @@ def lint_hlo_text(hlo_text: str, program: str) -> List[LintFinding]:
 
 def _phase_programs(engine, c: int, k: int):
     """The engine's separately-jitted phase programs with abstract args at
-    bucket (c, k) — the exact lowering recipe of
-    ``ParserEngine.phase_static_cost``."""
+    bucket (c, k), ready to lower."""
     import jax
     import jax.numpy as jnp
 

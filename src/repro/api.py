@@ -44,6 +44,7 @@ the facade is a first-class conformance route).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 import warnings
@@ -161,7 +162,7 @@ class ParserConfig:
     slo: Optional[SLOTargets] = None
     # observability (repro/obs): None = metrics only (tracing off); an
     # ObsConfig (or its dict) switches on spans / JSONL logs / profiler
-    # annotations / per-bucket hlo_stats static cost in ``stats()``
+    # annotations
     obs: Optional[ObsConfig] = None
 
     def __post_init__(self):
@@ -482,6 +483,7 @@ class ParseTicket:
             bucket=req.bucket,
             latency_s=req.latency_s,
             trace_id=req.trace_id,
+            root_span_id=req.root_span_id,
             tenant=req.tenant,
         )
         return self._result
@@ -800,16 +802,28 @@ class Parser:
         bucket: Optional[Tuple[int, int]] = None,
         latency_s: Optional[float] = None,
         trace_id: Optional[str] = None,
+        root_span_id: Optional[str] = None,
         tenant: Optional[str] = None,  # ticket plumbing; one-automaton
                                        # parsers have nothing per-tenant
     ) -> ParseResult:
+        # the request's root span is written before this runs, so the
+        # speculation span names its parent explicitly
+        span = (
+            self.obs.span(
+                "parse.speculation", trace_id=trace_id, parent_id=root_span_id
+            )
+            if root_span_id is not None
+            else contextlib.nullcontext()
+        )
+        with span:
+            speculation = self._speculation(slpf, bucket)
         return ParseResult(
             forest=slpf,
             backend=self.backend_name,
             bucket=bucket,
             latency_s=latency_s,
             n_chunks=self.config.n_chunks,
-            speculation=self._speculation(slpf, bucket),
+            speculation=speculation,
             trace_id=trace_id,
         )
 
@@ -881,8 +895,12 @@ class Parser:
 
         With tracing on (``ParserConfig(obs=ObsConfig(enabled=True))``)
         the call runs queue-free through the engine's phase-split route
-        (bit-identical to the fused program) so the span log carries one
-        ``parse.request`` root with real per-phase children.
+        (``ParserEngine.parse_phase_split``, bit-identical to the fused
+        program) so the span log carries one ``parse.request`` root with
+        real per-phase children and ``parse.speculation``.  ``submit``
+        keeps the fused route with tracing on, and its spans split that
+        route's time: queue wait, the batch's compute (host prep, device,
+        fetch, assembly) and speculation.
         """
         if self.obs.enabled or self.engine.mesh is not None:
             from .serve.parse_service import BucketStats
@@ -897,19 +915,19 @@ class Parser:
             obs = self.obs
             trace_id = obs.new_trace_id()
             t0 = time.perf_counter()
-            if obs.enabled:
-                with obs.span(
-                    "parse.request",
-                    trace_id=trace_id,
-                    bucket=list(bucket),
-                    backend=self.backend_name,
-                    n_chars=len(classes),
-                ):
-                    slpf = self.engine.parse_traced(
+            with obs.span(
+                "parse.request",
+                trace_id=trace_id,
+                bucket=list(bucket),
+                backend=self.backend_name,
+                n_chars=len(classes),
+            ) as root:
+                if obs.enabled:
+                    slpf = self.engine.parse_phase_split(
                         classes, n_chunks=self.config.n_chunks
                     )
-            else:
-                slpf = self.engine.parse(classes, n_chunks=self.config.n_chunks)
+                else:
+                    slpf = self.engine.parse(classes, n_chunks=self.config.n_chunks)
             latency = time.perf_counter() - t0
             # admission/SLO learn this route too; it never queues, so the
             # whole latency is compute
@@ -919,7 +937,8 @@ class Parser:
             m.counter("served_total", service="parse").inc()
             m.counter("chars_total", service="parse").inc(len(classes))
             return self._wrap(
-                slpf, bucket=bucket, latency_s=latency, trace_id=trace_id
+                slpf, bucket=bucket, latency_s=latency, trace_id=trace_id,
+                root_span_id=root.span_id,
             )
         return self.submit(text, deadline_s=deadline_s).result()
 
@@ -976,30 +995,13 @@ class Parser:
             out[bucket] = grade
         return out
 
-    def _hlo_static_cost(self, ps: Optional[Dict]) -> Optional[Dict[str, Any]]:
-        """Per-bucket static modeled cost (``launch/hlo_stats.py``) of the
-        compiled phase programs — attached only when tracing is on and the
-        ObsConfig keeps ``hlo`` enabled (one extra lowering per bucket,
-        memoized on the engine).  Mesh engines skip it: their phases fuse
-        inside one shard_map program with no per-phase HLO to attribute."""
-        cfg = self.obs.config
-        if not (self.obs.enabled and cfg.hlo) or self.engine.mesh is not None:
-            return None
-        buckets = ps["buckets"] if ps else {}
-        out: Dict[str, Any] = {}
-        for bucket in buckets:
-            c, k = bucket
-            out[f"{c}x{k}"] = self.engine.phase_static_cost(c, k)
-        return out
-
     def stats(self) -> Dict[str, Any]:
         """One aggregated view over both services + SLO conformance.
 
         ``parse``/``stream`` are the raw service stats (present once the
         corresponding service has been touched); ``metrics`` is the
         registry snapshot — the counter/gauge/histogram source of truth the
-        service dicts are views over; ``hlo`` (tracing on, single-device)
-        attaches each compiled bucket's static phase cost; ``slo.buckets``
+        service dicts are views over; ``slo.buckets``
         grades every observed bucket against the config targets
         (``p50_ok``/``p99_ok`` appear only when targets are set);
         ``speculation`` (sparse backend only, else None) reports the carried
@@ -1031,7 +1033,6 @@ class Parser:
             "parse": ps,
             "stream": ss,
             "metrics": self.obs.metrics.snapshot(),
-            "hlo": self._hlo_static_cost(ps),
             "analysis": self._analyze().to_dict(),
             "speculation": speculation,
             "slo": {
@@ -1260,6 +1261,8 @@ class ParserFleet:
         bucket=None,
         latency_s: Optional[float] = None,
         trace_id: Optional[str] = None,
+        root_span_id: Optional[str] = None,  # ticket plumbing; the fleet
+                                             # observes no speculation
         tenant: Optional[str] = None,
     ) -> ParseResult:
         cfg = self._configs.get(tenant) if tenant is not None else None

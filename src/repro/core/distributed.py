@@ -341,8 +341,8 @@ class DistributedEngine:
         chunks = eng._pad_to(classes, c, k)
         t = eng.tables
         self._count_allgather(c, self.chunk_axes)
-        col0, cols = self.chunk_program(t.N, t.I, t.F, chunks)
-        return eng._assemble(np.asarray(col0), np.asarray(cols), classes)
+        col0, cols = jax.block_until_ready(self.chunk_program(t.N, t.I, t.F, chunks))
+        return eng.fetch_assemble((col0,), (cols,), [classes])[0]
 
     def parse_batch(self, texts: Sequence, n_chunks: int = 8) -> List[SLPF]:
         """Many texts: batch slots over 'data' × chunks over 'pod'.
@@ -369,9 +369,10 @@ class DistributedEngine:
             for row, i in enumerate(idxs):
                 batch[row] = eng._pad_to(classes_list[i], c, k)
             self._count_allgather(B * c, self.batch_chunk_axes)
-            col0s, colss = self.batched_program(t.N, t.I, t.F, batch)
-            col0s = np.asarray(col0s)
-            colss = np.asarray(colss)
-            for row, i in enumerate(idxs):
-                results[i] = eng._assemble(col0s[row], colss[row], classes_list[i])
+            col0s, colss = jax.block_until_ready(
+                self.batched_program(t.N, t.I, t.F, batch)
+            )
+            group = [classes_list[i] for i in idxs]
+            for i, slpf in zip(idxs, eng.fetch_assemble(col0s, colss, group)):
+                results[i] = slpf
         return results  # type: ignore[return-value]

@@ -88,6 +88,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import ObsHandle
+from ..obs.metrics import Counter
 from .backend import (
     ParserBackend,
     get_backend,
@@ -270,7 +271,12 @@ class ParserEngine:
         self._phases: Optional[PhasePrograms] = None
         self._dist = None
         self._seen_batch_shapes: set = set()
-        self._hlo_memo: Dict[Tuple[int, int], Dict[str, Dict[str, float]]] = {}
+        # hot-path metric handles, bound once (per-bucket ones on first use)
+        m = self.obs.metrics
+        self._m_bucket_hits = m.counter("bucket_cache_hits_total")
+        self._m_bucket_misses = m.counter("bucket_cache_misses_total")
+        self._m_d2h_bytes = m.counter("d2h_bytes_total")
+        self._m_cells: Dict[Tuple[int, int], Tuple[Counter, Counter]] = {}
 
         def counted_core(N, I, F, chunks, _core=make_parse_core(self.backend)):
             # Python side effect at trace time: counts compiled programs.
@@ -382,28 +388,68 @@ class ParserEngine:
         for i, cls in enumerate(classes_list):
             groups.setdefault(self.bucket_shape(len(cls), n_chunks), []).append(i)
 
-        m = self.obs.metrics
+        obs, t = self.obs, self.tables
         results: List[Optional[SLPF]] = [None] * len(texts)
         for (c, k), idxs in sorted(groups.items()):
             B = _next_pow2(len(idxs))
             # bucket program-cache accounting: a (B, c, k) shape seen before
             # dispatches a compiled program; a new one is a re-jit event
             if (B, c, k) in self._seen_batch_shapes:
-                m.counter("bucket_cache_hits_total").inc()
+                self._m_bucket_hits.inc()
             else:
                 self._seen_batch_shapes.add((B, c, k))
-                m.counter("bucket_cache_misses_total").inc()
-            batch = np.full((B, c, k), self.tables.pad_class, dtype=np.int32)
-            for row, i in enumerate(idxs):
-                batch[row] = self._pad_to(classes_list[i], c, k)
-            col0s, colss = self._jit_batched(
-                self.tables.N, self.tables.I, self.tables.F, jnp.asarray(batch)
-            )
-            col0s = np.asarray(col0s)
-            colss = np.asarray(colss)
-            for row, i in enumerate(idxs):
-                results[i] = self._assemble(col0s[row], colss[row], classes_list[i])
+                self._m_bucket_misses.inc()
+            group = [classes_list[i] for i in idxs]
+            cells, text_cells = B * c * k, sum(len(cls) for cls in group)
+            with obs.span(
+                "parse.host_prep", bucket=[c, k], cells=cells, text_cells=text_cells
+            ):
+                batch = np.full((B, c, k), t.pad_class, dtype=np.int32)
+                for row, cls in enumerate(group):
+                    batch[row] = self._pad_to(cls, c, k)
+                batch = jnp.asarray(batch)
+            padded_total, text_total = self._cell_counters(c, k)
+            padded_total.inc(cells - text_cells)
+            text_total.inc(text_cells)
+            with obs.span("parse.device", bucket=[c, k], batch=B):
+                col0s, colss = jax.block_until_ready(
+                    self._jit_batched(t.N, t.I, t.F, batch)
+                )
+            for i, slpf in zip(idxs, self.fetch_assemble(col0s, colss, group)):
+                results[i] = slpf
         return results  # type: ignore[return-value]
+
+    def _cell_counters(self, c: int, k: int) -> Tuple[Counter, Counter]:
+        """``padded_cells_total`` / ``text_cells_total`` of one bucket."""
+        handles = self._m_cells.get((c, k))
+        if handles is None:
+            m, label = self.obs.metrics, f"{c}x{k}"
+            handles = self._m_cells[(c, k)] = (
+                m.counter("padded_cells_total", bucket=label),
+                m.counter("text_cells_total", bucket=label),
+            )
+        return handles
+
+    def fetch_assemble(self, col0s, colss, classes_list) -> List[SLPF]:
+        """Copy one program's packed columns to the host (``parse.fetch``)
+        and assemble each text's SLPF (``parse.assemble``).
+
+        Row r of ``col0s``/``colss`` (an array or a sequence of arrays)
+        belongs to ``classes_list[r]``.  The single-device and mesh routes
+        both finish here; callers block on the device first, so the fetch
+        span times the copy alone.
+        """
+        obs = self.obs
+        with obs.span("parse.fetch") as sp:
+            col0s, colss = jax.device_get((col0s, colss))
+            nbytes = sum(a.nbytes for a in jax.tree.leaves((col0s, colss)))
+            sp.set_attr("bytes", nbytes)
+        self._m_d2h_bytes.inc(nbytes)
+        with obs.span("parse.assemble", n_texts=len(classes_list)):
+            return [
+                self._assemble(col0, cols, classes)
+                for col0, cols, classes in zip(col0s, colss, classes_list)
+            ]
 
     def _assemble(self, col0, cols, classes) -> SLPF:
         n = len(classes)
@@ -432,7 +478,7 @@ class ParserEngine:
 
     # -------------------------------------------------------- observability
 
-    def parse_traced(self, text, n_chunks: int = 8) -> SLPF:
+    def parse_phase_split(self, text, n_chunks: int = 8) -> SLPF:
         """Parse one text with per-phase spans (the observability route).
 
         Runs the separately-jitted phase programs — the same bodies the
@@ -464,55 +510,6 @@ class ParserEngine:
         with obs.span("phase.host_build", n_chars=len(classes)):
             slpf = self._assemble(np.asarray(col0p), np.asarray(cols), classes)
         return slpf
-
-    def phase_static_cost(self, c: int, k: int) -> Dict[str, Dict[str, float]]:
-        """Static modeled cost of one bucket's compiled phase programs.
-
-        Lowers the reach / join / build&merge phase programs at this bucket's
-        shapes and runs ``launch/hlo_stats.py`` over the optimized HLO —
-        trip-count-aware flops / HBM-model bytes / collective bytes, the
-        modeled numbers ``Parser.stats()`` places next to the observed phase
-        times.  One extra lowering+compile per bucket, memoized forever, and
-        recorded once into the metrics registry as per-phase gauges.
-        """
-        key = (int(c), int(k))
-        if key in self._hlo_memo:
-            return self._hlo_memo[key]
-        from ..launch.hlo_stats import analyze_hlo_text
-
-        t = self.tables
-        eye = self.backend.identity_product(t.ell_pad, dtype=t.N.dtype)
-        chunks_sds = jax.ShapeDtypeStruct((c, k), jnp.int32)
-        P_sds = jax.ShapeDtypeStruct((c,) + eye.shape, eye.dtype)
-        J_sds = jax.ShapeDtypeStruct((c, t.ell_pad), jnp.float32)
-        phases = self.phases
-        lowered = {
-            "reach": (phases.reach, (t.N, chunks_sds)),
-            "join": (phases.join, (P_sds, t.I, t.F)),
-            "build_merge": (phases.build_merge, (t.N, chunks_sds, J_sds, J_sds)),
-        }
-        out: Dict[str, Dict[str, float]] = {}
-        total = {"flops": 0.0, "bytes": 0.0, "collective_bytes": 0.0}
-        bucket_label = f"{c}x{k}"
-        m = self.obs.metrics
-        for phase, (prog, args) in lowered.items():
-            stats = analyze_hlo_text(prog.lower(*args).compile().as_text())
-            entry = {
-                "flops": stats.flops,
-                "bytes": stats.bytes,
-                "collective_bytes": stats.coll_bytes,
-            }
-            out[phase] = entry
-            for field_name in total:
-                total[field_name] += entry[field_name]
-            m.gauge("hlo_flops", bucket=bucket_label, phase=phase).set(entry["flops"])
-            m.gauge("hlo_bytes", bucket=bucket_label, phase=phase).set(entry["bytes"])
-            m.gauge(
-                "hlo_collective_bytes", bucket=bucket_label, phase=phase
-            ).set(entry["collective_bytes"])
-        out["total"] = total
-        self._hlo_memo[key] = out
-        return out
 
     def count_accepting(self, text, n_chunks: int = 8) -> int:
         return self.parse(text, n_chunks).count_trees()

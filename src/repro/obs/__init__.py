@@ -59,16 +59,16 @@ class ObsConfig:
     ``enabled`` switches tracing on (metrics are ALWAYS collected — they are
     O(1) host mutations); ``span_log`` adds a JSONL sink for finished spans;
     ``profiler`` wraps every span in a ``jax.profiler.TraceAnnotation`` so
-    phase names appear on real profiler timelines; ``hlo`` attaches
-    ``launch/hlo_stats.py`` static cost to each compiled bucket in
-    ``Parser.stats()`` (one extra lowering per bucket, memoized);
-    ``max_spans`` bounds the tracer's in-memory ring buffer.
+    phase names appear on real profiler timelines; ``max_spans`` bounds
+    the tracer's in-memory ring buffer.  ``hlo`` is accepted and ignored:
+    ``Parser.stats()`` no longer lowers phase programs for static cost,
+    and the field goes once the benchmark harness stops passing it.
     """
 
     enabled: bool = False
     span_log: Optional[str] = None
     profiler: bool = False
-    hlo: bool = True
+    hlo: bool = False
     max_spans: int = 4096
 
     def __post_init__(self):

@@ -66,6 +66,12 @@ METRIC_CATALOG: Dict[str, Tuple] = {
     "compiled_programs_total": ("counter", "device programs traced (re-jit events)"),
     "bucket_cache_hits_total": ("counter", "parses served by an already-compiled bucket"),
     "bucket_cache_misses_total": ("counter", "parses that compiled a new bucket shape"),
+    # fused-route cells and copies (core/engine.py parse_batch)
+    "padded_cells_total": (
+        "counter", "PAD cells the fused program ran (B·c·k less text), per bucket",
+    ),
+    "text_cells_total": ("counter", "text characters the fused program ran, per bucket"),
+    "d2h_bytes_total": ("counter", "packed-column bytes copied device to host"),
     # fleet transition-table compile cache (core/fleet.py)
     "table_cache_hits_total": (
         "counter", "tenant table compiles served from the process-wide cache",
@@ -97,12 +103,6 @@ METRIC_CATALOG: Dict[str, Tuple] = {
     "speculation_width": (
         "histogram", "observed feasible-start width per parse",
         (1, 2, 4, 8, 16, 32, 64, 128, 256, 512),
-    ),
-    # static modeled cost (launch/hlo_stats.py, per compiled bucket + phase)
-    "hlo_flops": ("gauge", "static flops of one compiled phase program"),
-    "hlo_bytes": ("gauge", "static HBM-model bytes of one compiled phase program"),
-    "hlo_collective_bytes": (
-        "gauge", "static collective bytes of one compiled phase program",
     ),
     # tracing plumbing
     "spans_recorded_total": ("counter", "finished spans recorded by the tracer"),

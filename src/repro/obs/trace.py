@@ -36,17 +36,32 @@ Span taxonomy (documented in ROADMAP "Observability"):
 
   parse.request            root — one submit/parse lifetime (queue + device + host)
   parse.queue_wait         submit → batch pickup (service queue residency)
-  parse.batch_compute      the batched device program serving the bucket
+  parse.batch_compute      the batch serving the bucket; live around the
+                           engine call for the batch head, a retroactive
+                           copy for every rider
+    parse.host_prep        pad into the (B, c, k) bucket, host → device
+                           (attrs ``cells`` = B·c·k, ``text_cells``)
+    parse.device           the fused program, blocked on its results
+    parse.fetch            device → host copy of the packed columns (``bytes``)
+    parse.assemble         host SLPF assembly of every text of the batch
+  parse.speculation        observed speculation widths (``Parser._wrap``),
+                           parented to the root after the root is written
   stream.append            root — one append lifetime
   stream.append_queue_wait append → piece-batch pickup
   stream.append_compute    the batched tail reach + compose
   stream.query             SLPF / acceptance materialization of a prefix
   stream.edit              one mid-text splice (segment-tree recompose path)
+
+A traced ``Parser.parse`` runs ``ParserEngine.parse_phase_split`` instead of
+the fused program, queue-free, and splits the device work by phase under
+its ``parse.request``:
+
   phase.reach              chunk-product reach (device)
   phase.join               exclusive scan over stacked products (device)
   phase.build_merge        builder&merger over join entries (device)
   phase.host_build         host-side SLPF assembly (unpack + wrap)
-  phase.device_parse       one fused/mesh device program (phases not split)
+  phase.device_parse       a mesh's one sharded program (phases not split),
+                           then ``parse.fetch`` / ``parse.assemble``
 """
 
 from __future__ import annotations
@@ -104,6 +119,7 @@ class _NullSpan:
     """Attribute sink for disabled tracers (``set_attr`` is a no-op)."""
 
     __slots__ = ()
+    span_id = None    # nothing recorded: children have no parent to name
 
     def set_attr(self, key: str, value: Any) -> None:
         pass

@@ -600,11 +600,21 @@ class ParseService:
                 keep.append(req)
         self._queue = keep
 
+        obs = self.engine.obs
         picked_at = time.perf_counter()
-        slpfs = self._execute(head_bucket, batch)
+        # live around the engine call, so the engine's spans nest under it;
+        # riders get a retroactive copy below
+        with obs.span(
+            "parse.batch_compute",
+            trace_id=head.trace_id,
+            parent_id=head.root_span_id,
+            bucket=list(head_bucket),
+            batch_size=len(batch),
+            tenant=head.tenant,
+        ):
+            slpfs = self._execute(head_bucket, batch)
         now = time.perf_counter()
         compute_s = now - picked_at
-        obs = self.engine.obs
         stats = self._buckets.setdefault(head_bucket, BucketStats())
         for req, slpf in zip(batch, slpfs):
             req.slpf = slpf
@@ -632,16 +642,17 @@ class ParseService:
                     bucket=list(head_bucket),
                     tenant=req.tenant,
                 )
-                obs.emit(
-                    "parse.batch_compute",
-                    t_start_s=picked_at,
-                    duration_s=compute_s,
-                    trace_id=req.trace_id,
-                    parent_id=req.root_span_id,
-                    bucket=list(head_bucket),
-                    batch_size=len(batch),
-                    tenant=req.tenant,
-                )
+                if req is not head:
+                    obs.emit(
+                        "parse.batch_compute",
+                        t_start_s=picked_at,
+                        duration_s=compute_s,
+                        trace_id=req.trace_id,
+                        parent_id=req.root_span_id,
+                        bucket=list(head_bucket),
+                        batch_size=len(batch),
+                        tenant=req.tenant,
+                    )
             self._done.append(req)
         stats.batches += 1
         self.batches_run += 1

@@ -10,9 +10,13 @@ Covers the acceptance bars of the observability PR:
     Prometheus rendering;
   * the shared BENCH_*.json perf-trajectory schema;
   * facade integration — a traced ``Parser.parse`` leaves a complete span
-    tree in the JSONL log, a trace ID survives the submit → ticket.result()
-    round trip, ``Parser.stats()`` is a live registry view, and the hlo
-    static cost attaches per compiled bucket;
+    tree of the phase-split route in the JSONL log, and a ``submit`` →
+    ticket.result() round trip one of the fused route (queue wait, batch
+    compute over host prep / device / fetch / assembly, speculation),
+    batch riders included; tracing changes no forest; ``Parser.stats()``
+    is a live registry view;
+  * the fused route's counters (padded / text cells per bucket, device to
+    host bytes), and the mesh routes' shared fetch / assembly spans;
   * the split queue-wait / compute latency windows wrap independently at
     ``LATENCY_WINDOW`` samples (regression: one window used to conflate
     wait with compute).
@@ -20,13 +24,13 @@ Covers the acceptance bars of the observability PR:
 
 import json
 
+import numpy as np
 import pytest
 
 import repro
 from repro.obs import (
     METRIC_CATALOG,
     MetricsRegistry,
-    ObsConfig,
     Tracer,
     prometheus_text,
     read_spans_jsonl,
@@ -196,6 +200,28 @@ def traced_parser(tmp_path):
     p.close()
 
 
+FUSED_CHILDREN = ["parse.host_prep", "parse.device", "parse.fetch",
+                  "parse.assemble"]
+
+
+def _children(spans, parent):
+    return sorted((s for s in spans if s["parent_id"] == parent["span_id"]
+                   and s["trace_id"] == parent["trace_id"]),
+                  key=lambda s: s["t_start_s"])
+
+
+def _request_tree(spans, trace_id):
+    """The fused route's tree of one request: the root's direct children by
+    name, and the children of its ``parse.batch_compute``."""
+    tree = validate_span_tree(spans, trace_id)
+    root = tree["root"]
+    assert root["name"] == "parse.request"
+    direct = {s["name"]: s for s in _children(spans, root)}
+    assert set(direct) == {"parse.queue_wait", "parse.batch_compute",
+                           "parse.speculation"}
+    return root, direct, _children(spans, direct["parse.batch_compute"])
+
+
 def test_traced_parse_emits_complete_span_tree(traced_parser):
     p, log = traced_parser
     r = p.parse("abab" * 8)
@@ -207,7 +233,7 @@ def test_traced_parse_emits_complete_span_tree(traced_parser):
     assert tree["root"]["name"] == "parse.request"
     child_names = {c["name"] for c in tree["children"]}
     assert {"phase.reach", "phase.join", "phase.build_merge",
-            "phase.host_build"} <= child_names
+            "phase.host_build", "parse.speculation"} <= child_names
 
 
 def test_trace_id_survives_submit_roundtrip(traced_parser):
@@ -215,20 +241,178 @@ def test_trace_id_survives_submit_roundtrip(traced_parser):
     ticket = p.submit("abab" * 4)
     r = ticket.result()
     assert r.ok and r.trace_id is not None
-    tree = validate_span_tree(read_spans_jsonl(log), r.trace_id)
-    assert tree["root"]["name"] == "parse.request"
-    names = {c["name"] for c in tree["children"]}
-    assert {"parse.queue_wait", "parse.batch_compute"} <= names
+    _, _, compute_children = _request_tree(read_spans_jsonl(log), r.trace_id)
+    assert [s["name"] for s in compute_children] == FUSED_CHILDREN
+
+
+@pytest.mark.parametrize("backend", ["jnp", "sparse"])
+def test_fused_children_fit_their_parents(backend):
+    p = repro.Parser(repro.ParserConfig(
+        regex=PATTERN, n_chunks=4, backend=backend, obs={"enabled": True},
+    ))
+    r = p.submit("ab" * 40).result()
+    spans = [s.to_dict() for s in p.obs.tracer.drain()]
+    root, direct, kids = _request_tree(spans, r.trace_id)
+    compute = direct["parse.batch_compute"]
+    assert sum(s["duration_s"] for s in kids) <= compute["duration_s"]
+    end = compute["t_start_s"] + compute["duration_s"]
+    for s in kids:
+        assert compute["t_start_s"] <= s["t_start_s"]
+        assert s["t_start_s"] + s["duration_s"] <= end
+    assert (direct["parse.queue_wait"]["duration_s"] + compute["duration_s"]
+            <= root["duration_s"])
+    # speculation runs once the root is written, and names it as parent
+    spec = direct["parse.speculation"]
+    assert spec["t_start_s"] >= root["t_start_s"] + root["duration_s"]
+    assert (r.speculation is not None) == (backend == "sparse")
+    p.close()
+
+
+def test_batch_riders_get_complete_trees(traced_parser):
+    p, log = traced_parser
+    tickets = [p.submit(t) for t in ("ab" * 5, "abab" * 3, "b" * 9)]
+    results = [t.result() for t in tickets]
+    spans = read_spans_jsonl(log)
+    with_children = []
+    for r in results:
+        _, direct, kids = _request_tree(spans, r.trace_id)
+        assert direct["parse.batch_compute"]["attrs"]["batch_size"] == 3
+        if kids:
+            with_children.append(kids)
+    # the batch head's compute span is live and holds the engine's spans;
+    # the riders' retroactive copies have none
+    assert len(with_children) == 1
+    assert [s["name"] for s in with_children[0]] == FUSED_CHILDREN
+    assert with_children[0][0]["attrs"]["text_cells"] == 10 + 12 + 9
+
+
+def test_untraced_parser_records_no_spans():
+    p = repro.Parser(repro.ParserConfig(regex=PATTERN, n_chunks=4))
+    p.parse("abab")
+    p.submit("ab").result()
+    p.engine.parse_phase_split("abab")
+    assert p.obs.tracer.drain() == []
+    assert "spans_recorded_total" not in p.stats()["metrics"]
+    p.close()
+
+
+def _value(p, name, **labels):
+    for series in p.stats()["metrics"].get(name, []):
+        if series["labels"] == labels:
+            return series["value"]
+    return 0.0
+
+
+def test_cell_counters_count_padding():
+    p = repro.Parser(repro.ParserConfig(regex=PATTERN, n_chunks=4))
+    n = 37
+    c, k = p.engine.bucket_shape(n, 4)
+    assert (c, k) == (4, 16)
+    p.parse("ab" * 18 + "a")
+    assert _value(p, "text_cells_total", bucket="4x16") == n
+    assert _value(p, "padded_cells_total", bucket="4x16") == c * k - n
+    # three same-bucket texts ride one batch of B = 4 slots
+    tickets = [p.submit("ab" * 17), p.submit("a" * 40), p.submit("b" * 33)]
+    for t in tickets:
+        t.result()
+    assert _value(p, "text_cells_total", bucket="4x16") == n + 34 + 40 + 33
+    assert _value(p, "padded_cells_total", bucket="4x16") == (
+        (c * k - n) + (4 * c * k - (34 + 40 + 33)))
+    p.close()
+
+
+def test_d2h_bytes_counts_fetched_arrays():
+    p = repro.Parser(repro.ParserConfig(regex=PATTERN, n_chunks=4,
+                                        obs={"enabled": True}))
+    eng = p.engine
+    texts = ["ab" * 9, "ba" * 7, "abab"]
+    classes = [eng.classes_of_text(t) for t in texts]
+    c, k = eng.bucket_shape(18, 4)
+    batch = np.full((4, c, k), eng.tables.pad_class, dtype=np.int32)
+    for row, cls in enumerate(classes):
+        batch[row] = eng._pad_to(cls, c, k)
+    t = eng.tables
+    col0s, colss = eng._jit_batched(t.N, t.I, t.F, batch)
+    want = np.asarray(col0s).nbytes + np.asarray(colss).nbytes
+    before = _value(p, "d2h_bytes_total")
+    eng.parse_batch(texts, n_chunks=4)
+    assert _value(p, "d2h_bytes_total") - before == want
+    fetch = [s for s in p.obs.tracer.drain() if s.name == "parse.fetch"]
+    assert [s.attrs["bytes"] for s in fetch] == [want]
+    p.close()
+
+
+def test_parse_phase_split_emits_phase_spans(traced_parser):
+    p, _ = traced_parser
+    text = "ab" * 21
+    split = p.engine.parse_phase_split(text, n_chunks=4)
+    names = [s.name for s in p.obs.tracer.drain()]
+    assert names == ["phase.reach", "phase.join", "phase.build_merge",
+                     "phase.host_build"]
+    assert np.array_equal(split.pack(), p.submit(text).result().forest.pack())
+
+
+def test_mesh_direct_route_spans():
+    # a (1, 1) mesh on the plain suite's one device: the sharded programs
+    # run without collectives, through the same spans as a real mesh
+    p = repro.Parser(repro.ParserConfig(
+        regex=PATTERN, n_chunks=4, mesh="host", obs={"enabled": True},
+    ))
+    r = p.parse("ab" * 12)
+    spans = [s.to_dict() for s in p.obs.tracer.drain()]
+    tree = validate_span_tree(spans, r.trace_id)
+    root = tree["root"]
+    direct = [s["name"] for s in _children(spans, root)]
+    assert direct == ["phase.device_parse", "parse.speculation"]
+    device = _children(spans, root)[0]
+    assert [s["name"] for s in _children(spans, device)] == [
+        "parse.fetch", "parse.assemble"]
+    p.close()
+
+
+@pytest.mark.slow
+def test_mesh_routes_fetch_and_assemble_4dev():
+    """Both mesh routes on four virtual CPU devices (the device count is
+    fixed at jax start, so a fresh process sets it): the chunk-sharded
+    direct parse and the batch-sharded service route finish through the
+    engine's fetch / assembly spans, with forests equal to one device's."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    code = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, numpy as np, repro
+assert len(jax.devices()) == 4
+cfg = dict(regex="(a|b|ab)+", n_chunks=4)
+mesh = repro.Parser(repro.ParserConfig(mesh="host", obs={"enabled": True}, **cfg))
+one = repro.Parser(repro.ParserConfig(**cfg))
+for route in ("parse", "submit"):
+    text = "ab" * 19
+    r = mesh.parse(text) if route == "parse" else mesh.submit(text).result()
+    assert np.array_equal(r.forest.pack(), one.parse(text).forest.pack())
+    names = [s.name for s in mesh.obs.tracer.drain() if s.trace_id == r.trace_id]
+    assert "parse.fetch" in names and "parse.assemble" in names, (route, names)
+print("MESH-SPANS-OK")
+"""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(Path(__file__).parents[1] / "src")
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "MESH-SPANS-OK" in out.stdout
 
 
 def test_traced_route_bit_identical_to_fused(traced_parser):
-    import numpy as np
-
     p, _ = traced_parser
     plain = repro.Parser(repro.ParserConfig(regex=PATTERN, n_chunks=4))
     text = "ab" * 37
-    assert np.array_equal(p.parse(text).forest.pack(),
-                          plain.parse(text).forest.pack())
+    want = plain.parse(text).forest.pack()
+    assert np.array_equal(p.parse(text).forest.pack(), want)
+    assert np.array_equal(p.submit(text).result().forest.pack(), want)
     plain.close()
 
 
@@ -261,27 +445,6 @@ def test_stats_is_live_registry_view(traced_parser):
     second = served()
     assert second == first + 2            # counters only ever move up
     validate_metric_names(p.stats()["metrics"])
-
-
-def test_stats_attaches_hlo_static_cost(traced_parser):
-    p, _ = traced_parser
-    p.parse("abab" * 8)
-    hlo = p.stats()["hlo"]
-    assert hlo, "traced parser with hlo=True must report static cost"
-    for bucket, phases in hlo.items():
-        assert set(phases) == {"reach", "join", "build_merge", "total"}
-        assert phases["total"]["flops"] > 0
-        assert phases["total"]["bytes"] > 0
-
-
-def test_hlo_off_by_config(tmp_path):
-    p = repro.Parser(repro.ParserConfig(
-        regex=PATTERN, n_chunks=4,
-        obs=ObsConfig(enabled=True, hlo=False),
-    ))
-    p.parse("abab")
-    assert p.stats()["hlo"] is None
-    p.close()
 
 
 # ------------------------------------------- latency window split
