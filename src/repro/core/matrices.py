@@ -141,17 +141,22 @@ def pack_bits(mat: np.ndarray, axis: int = -1) -> np.ndarray:
     return np.moveaxis(packed, -1, axis if axis >= 0 else len(packed.shape) + axis)
 
 
-_BIT_SHIFTS = np.arange(32, dtype=np.uint32)
-
-
 def unpack_bits(packed: np.ndarray, n: int, axis: int = -1) -> np.ndarray:
-    """Inverse of :func:`pack_bits`."""
-    packed = np.asarray(packed, dtype=np.uint32)
+    """Inverse of :func:`pack_bits`.
+
+    Unpacks byte-wise: bit ``b`` of little-endian word ``w`` is bit
+    ``b % 8`` of byte ``4w + b // 8``, so one ``np.unpackbits`` pass over
+    the uint8 view writes exactly the ``n`` wanted columns and allocates
+    nothing but the output.
+    """
+    packed = np.asarray(packed)
     last = axis == -1 or axis == packed.ndim - 1
     if not last:
         packed = np.moveaxis(packed, axis, -1)
-    bits = (packed[..., :, None] >> _BIT_SHIFTS) & np.uint32(1)
-    flat = bits.reshape(packed.shape[:-1] + (-1,))[..., :n].astype(bool)
+    words = np.ascontiguousarray(packed, dtype="<u4")
+    flat = np.unpackbits(
+        words.view(np.uint8), axis=-1, count=n, bitorder="little"
+    ).view(bool)
     if last:
         return flat
     return np.moveaxis(flat, -1, axis if axis >= 0 else len(flat.shape) + axis)

@@ -70,3 +70,31 @@ def test_property_engine_equals_serial():
         assert np.array_equal(ref.columns, got.columns)
 
     run()
+
+
+def test_assemble_matches_wordwise_formula():
+    """The engine seam: ``_assemble`` of a 2-chunk TRAFFIC text's packed
+    columns equals the word-wise shift-and-mask formula over the same
+    concatenated words, and the serial oracle."""
+    from repro.core.segments import compute_segments
+
+    eng = ParserEngine(
+        compute_segments(r"((GET|POST|PUT) /([a-z0-9]|/)* ([0-9]{3}) (ok|err|-)\n)+"),
+        backend="sparse",
+    )
+    text = "GET /a/b1 200 ok\nPOST /x 404 err\nPUT // 500 -\n"
+    classes = eng.classes_of_text(text)
+    c, k = eng.bucket_shape(len(classes), 2)
+    assert c == 2
+    t = eng.tables
+    col0s, colss = eng._jit_batched(t.N, t.I, t.F, eng._pad_to(classes, c, k)[None])
+    col0, cols = np.asarray(col0s[0]), np.asarray(colss[0])
+    got = eng._assemble(col0, cols, classes)
+
+    W = cols.shape[-1]
+    packed = np.concatenate([col0[None], cols.reshape(-1, W)[: len(classes)]])
+    bits = (packed[..., :, None] >> np.arange(32, dtype=np.uint32)) & np.uint32(1)
+    want = bits.reshape(packed.shape[0], -1)[:, : t.ell].astype(bool)
+    assert got.columns.shape == (len(classes) + 1, t.ell)
+    assert np.array_equal(got.columns, want)
+    assert np.array_equal(got.columns, parse_serial_matrix(eng.matrices, text).columns)

@@ -62,6 +62,41 @@ def test_pack_unpack_roundtrip_any_width(n, axis):
         _check_roundtrip(seed, n, axis)
 
 
+def _unpack_wordwise(packed: np.ndarray, n: int) -> np.ndarray:
+    """Word-wise shift-and-mask reference of unpack_bits along the last axis."""
+    bits = (packed[..., :, None] >> np.arange(32, dtype=np.uint32)) & np.uint32(1)
+    flat = bits.reshape(packed.shape[:-1] + (32 * packed.shape[-1],))
+    return flat[..., :n].astype(bool)
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "strided", "zero_rows"])
+@pytest.mark.parametrize("axis", [-1, 0])
+@pytest.mark.parametrize("n", [1, 31, 32, 33, 37, 64, 257])
+def test_unpack_bits_matches_wordwise_golden(n, axis, layout):
+    """Full random words, so every padding bit above n is set and must be
+    dropped; a strided view and a zero-row input take the same path."""
+    rng = np.random.default_rng(n)
+    W = -(-n // 32)
+    rows = 0 if layout == "zero_rows" else 5
+    words = rng.integers(0, 1 << 32, size=(2 * rows, W + 1), dtype=np.uint64)
+    words = words.astype(np.uint32)
+    if layout == "strided":
+        packed = words[::2, :W]              # every other row, last word dropped
+        assert not packed.flags.c_contiguous
+    else:
+        packed = np.ascontiguousarray(words[:rows, :W])
+    want = _unpack_wordwise(packed, n)
+    if axis == 0:
+        got = unpack_bits(packed.T, n, axis=0)
+        assert got.dtype == bool and got.shape == (n, rows)
+        assert np.array_equal(got, want.T)
+        return
+    got = unpack_bits(packed, n, axis=axis)
+    assert got.dtype == bool and got.shape == (rows, n)
+    assert got.flags.c_contiguous and got.flags.writeable
+    assert np.array_equal(got, want)
+
+
 def test_pack_unpack_roundtrip_property():
     hyp = pytest.importorskip("hypothesis")
     st = pytest.importorskip("hypothesis.strategies")
