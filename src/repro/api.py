@@ -888,16 +888,20 @@ class Parser:
         """Parse one text synchronously through the same admission path as
         ``submit`` (stats/SLO observe it).
 
-        On a mesh config this is the long-text route: the engine's
-        single-text distributed program shards the chunk dim over EVERY
-        chunk mesh axis ('pod' × 'data') — ``parse_batch`` instead keeps
-        batch slots over 'data' and chunks over 'pod'.
+        On a mesh config this is the long-text route, queue-free: the
+        engine's single-text distributed program shards the chunk dim over
+        EVERY chunk mesh axis ('pod' × 'data') — ``parse_batch`` instead
+        keeps batch slots over 'data' and chunks over 'pod'.
 
         With tracing on (``ParserConfig(obs=ObsConfig(enabled=True))``)
         the call runs queue-free through the engine's phase-split route
         (``ParserEngine.parse_phase_split``, bit-identical to the fused
         program) so the span log carries one ``parse.request`` root with
-        real per-phase children and ``parse.speculation``.  ``submit``
+        real per-phase children and ``parse.speculation``.  On a mesh the
+        phases are sharded programs: ``parse.host_prep`` (the sharded put),
+        ``phase.reach``, ``phase.join`` (the product all-gather and the
+        replicated join), ``phase.build_merge`` and ``phase.host_build``
+        (fetch from every device and assembly).  ``submit``
         keeps the fused route with tracing on, and its spans split that
         route's time: queue wait, the batch's compute (host prep, device,
         fetch, assembly) and speculation.

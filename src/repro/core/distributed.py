@@ -48,14 +48,28 @@ Routes
 
   parse          one text; the chunk dim takes EVERY mesh axis the logical
                  'chunk' rule names (``MeshRules``: 'chunk' → ('pod','data'))
-                 — maximum chunk parallelism for one long text.
+                 — maximum chunk parallelism for one long text, in one
+                 program (``chunk_program``).
+  parse_phase_split
+                 the same text route as three sharded programs with host
+                 seams — ``reach_program`` (step 1), ``join_program``
+                 (steps 2-3), ``build_merge_program`` (step 4) — built from
+                 the same body pieces as ``chunk_program``, so bit-identical
+                 to it; each phase's span blocks on its result.  The direct
+                 route a traced ``Parser.parse`` takes on a mesh.
   parse_batch    many texts; the slot/bucket batch dim shards over 'data'
                  (pure DP, no collective) and the chunk dim keeps 'pod' —
                  the composition falls out of ``MeshRules``' duplicate-axis
                  dropping once batch is restricted to 'data'.  The all-gather
                  of step 2 then runs over 'pod' only, per batch shard.
   join_products  the streaming route: a (c, ℓp, ℓp) product stack sharded
-                 over the chunk axes → replicated (Jf, Jb, packed C₀).
+                 over the chunk axes → replicated (Jf, Jb, packed C₀), through
+                 the phase-split route's ``join_program``.
+
+Every route finishes in the engine's ``fetch_assemble`` (spans
+``parse.fetch`` / ``parse.assemble``); ``parse`` and ``parse_batch`` open
+``parse.host_prep`` (padding and the sharded put) and ``parse.device``
+(the blocked program, attr ``chips``) as the single-device route does.
 
 ``ParserEngine(mesh=...)`` builds this layer lazily and routes its
 ``parse``/``parse_batch`` through it, so ``ParseService``, ``StreamService``
@@ -67,6 +81,7 @@ PAD rows/chunks are semantics-free, so divisibility padding is free).
 
 from __future__ import annotations
 
+from functools import cached_property
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -79,6 +94,8 @@ from ..parallel.sharding import MeshRules, spec_axes
 from .engine import _next_pow2, join_with_col0, _resolve_engine
 from .scan import linear_index
 from .slpf import SLPF
+
+_REP = PartitionSpec()
 
 
 def _entry(axes: Tuple[str, ...]):
@@ -94,6 +111,16 @@ def _gather(x: jnp.ndarray, axes: Tuple[str, ...], axis: int) -> jnp.ndarray:
     if not axes:
         return x
     return jax.lax.all_gather(x, tuple(axes), axis=axis, tiled=True)
+
+
+def _own_entries(Jf, Jb, axes: Tuple[str, ...], f: int, axis: int):
+    """This device's ``f`` chunks' join entries, sliced along ``axis`` of the
+    replicated (…, c, ℓp) entries at its ``linear_index`` over ``axes``."""
+    start = linear_index(axes) * f
+    return (
+        jax.lax.dynamic_slice_in_dim(Jf, start, f, axis),
+        jax.lax.dynamic_slice_in_dim(Jb, start, f, axis),
+    )
 
 
 def _round_up(n: int, m: int) -> int:
@@ -125,9 +152,6 @@ class DistributedEngine:
         )
         self.batch_axes = spec_axes(bspec, 0)
         self.batch_chunk_axes = spec_axes(bspec, 1)
-        self._chunk_prog = None
-        self._batched_prog = None
-        self._join_prog = None
 
     # ------------------------------------------------------------- geometry
 
@@ -159,80 +183,115 @@ class DistributedEngine:
         eye = self.engine.backend.identity_product(t.ell_pad, dtype=t.N.dtype)
         return int(eye.size) * eye.dtype.itemsize
 
-    def _count_allgather(self, n_products: int, gather_axes) -> None:
-        """Record the product-stack collective payload for one dispatch.
+    def _count_allgather(self, n_products: int, gather_axes) -> int:
+        """Record the product-stack collective payload for one dispatch and
+        return it in bytes.
 
         The contract's step 2 moves the full (c, …) stack to every device;
         the counted payload is the gathered stack's bytes (text-length
         independent).  A degenerate mesh (no gather axes) moves nothing.
         """
         if not gather_axes:
-            return
-        self.engine.obs.metrics.counter("allgather_payload_bytes_total").inc(
-            n_products * self._product_nbytes()
-        )
+            return 0
+        nbytes = n_products * self._product_nbytes()
+        self.engine.obs.metrics.counter("allgather_payload_bytes_total").inc(nbytes)
+        return nbytes
 
-    def _rep(self) -> NamedSharding:
-        return NamedSharding(self.mesh, PartitionSpec())
+    def _program(self, body, in_specs, out_specs):
+        """``body`` as one jitted ``shard_map`` over this mesh, every operand
+        and result placed as its spec says; each trace counts as a compile."""
 
-    # ------------------------------------------------- single-text program
-
-    @property
-    def chunk_program(self):
-        """Jitted single-text route: chunks (c, k) sharded over chunk_axes."""
-        if self._chunk_prog is None:
-            self._chunk_prog = self._build_chunk_program()
-        return self._chunk_prog
-
-    def _build_chunk_program(self):
-        backend = self.engine.backend
-        axes = self.chunk_axes
-        spec = PartitionSpec(_entry(axes))
-
-        def body(N, I, F, chunks):  # chunks: (f, k) shard-local rows
+        def counted(*args):
             self._bump()
-            P_local = backend.reach(N, chunks)            # (f, ℓp, ℓp)
-            P_all = _gather(P_local, axes, axis=0)        # (c, ℓp, ℓp) repl.
-            Jf, Jb, col0p = join_with_col0(backend, P_all, I, F)
-            f = P_local.shape[0]
-            start = linear_index(axes) * f
-            Jf_loc = jax.lax.dynamic_slice_in_dim(Jf, start, f, 0)
-            Jb_loc = jax.lax.dynamic_slice_in_dim(Jb, start, f, 0)
-            M = backend.build_merge_packed(N, chunks, Jf_loc, Jb_loc)
-            return col0p, M
+            return body(*args)
 
         program = jax.shard_map(
-            body,
+            counted,
             mesh=self.mesh,
             check_vma=False,
-            in_specs=(PartitionSpec(), PartitionSpec(), PartitionSpec(), spec),
-            out_specs=(PartitionSpec(), spec),
+            in_specs=in_specs,
+            out_specs=out_specs,
         )
-        rep = self._rep()
         return jax.jit(
             program,
-            in_shardings=(rep, rep, rep, NamedSharding(self.mesh, spec)),
-            out_shardings=(rep, NamedSharding(self.mesh, spec)),
+            in_shardings=self._named(in_specs),
+            out_shardings=self._named(out_specs),
+        )
+
+    def _named(self, specs):
+        """NamedSharding(s) on this mesh for one spec or a tuple of specs."""
+        if isinstance(specs, PartitionSpec):
+            return NamedSharding(self.mesh, specs)
+        return tuple(NamedSharding(self.mesh, s) for s in specs)
+
+    # ------------------------------------------------- single-text programs
+    #
+    # The single-text route's shard-local pieces: the fused chunk program
+    # composes them, the phase-split route runs each as its own program.
+
+    @property
+    def _chunk_spec(self) -> PartitionSpec:
+        return PartitionSpec(_entry(self.chunk_axes))
+
+    def _join_local(self, P, I, F):
+        """Step 2 + 3: all-gather this device's (f, …) products over the
+        chunk axes, then the replicated join."""
+        return join_with_col0(self.engine.backend, _gather(P, self.chunk_axes, 0), I, F)
+
+    def _build_merge_local(self, N, chunks, Jf, Jb):
+        """Step 4: build&merge of this device's (f, k) chunks from its slice
+        of the replicated entries."""
+        Jf, Jb = _own_entries(Jf, Jb, self.chunk_axes, chunks.shape[0], axis=0)
+        return self.engine.backend.build_merge_packed(N, chunks, Jf, Jb)
+
+    @cached_property
+    def chunk_program(self):
+        """Jitted single-text route: chunks (c, k) sharded over chunk_axes,
+        reach → all-gather + join → build&merge in one program."""
+        backend, spec = self.engine.backend, self._chunk_spec
+
+        def body(N, I, F, chunks):  # chunks: (f, k) shard-local rows
+            P = backend.reach(N, chunks)                  # (f, …) local
+            Jf, Jb, col0p = self._join_local(P, I, F)     # replicated
+            return col0p, self._build_merge_local(N, chunks, Jf, Jb)
+
+        return self._program(body, (_REP, _REP, _REP, spec), (_REP, spec))
+
+    @cached_property
+    def reach_program(self):
+        """Phase-split route, reach: (N, chunks) → products, both sharded
+        over the chunk axes (no communication)."""
+        spec = self._chunk_spec
+        return self._program(self.engine.backend.reach, (_REP, spec), spec)
+
+    @cached_property
+    def join_program(self):
+        """Sharded (c, …) product stack → replicated (Jf, Jb, packed C₀):
+        the phase-split route's join and the streaming route."""
+        spec = self._chunk_spec
+        return self._program(self._join_local, (spec, _REP, _REP), (_REP,) * 3)
+
+    @cached_property
+    def build_merge_program(self):
+        """Phase-split route, build&merge: sharded chunks and replicated
+        entries → packed columns sharded over the chunk axes."""
+        spec = self._chunk_spec
+        return self._program(
+            self._build_merge_local, (_REP, spec, _REP, _REP), spec
         )
 
     # ---------------------------------------------------- batched program
 
-    @property
+    @cached_property
     def batched_program(self):
         """Jitted batched route: (B, c, k) with batch over 'data', chunks
         over 'pod'."""
-        if self._batched_prog is None:
-            self._batched_prog = self._build_batched_program()
-        return self._batched_prog
-
-    def _build_batched_program(self):
         backend = self.engine.backend
         b_axes, c_axes = self.batch_axes, self.batch_chunk_axes
         spec_in = PartitionSpec(_entry(b_axes), _entry(c_axes))
         spec_b = PartitionSpec(_entry(b_axes))
 
         def body(N, I, F, batch):  # batch: (B_loc, c_loc, k) shard-local
-            self._bump()
             reach_b = backend.lift_batch(lambda ch: backend.reach(N, ch))
             P_local = reach_b(batch)                      # (B_loc, c_loc, ℓp, ℓp)
             P_all = _gather(P_local, c_axes, axis=1)      # (B_loc, c, ℓp, ℓp)
@@ -240,64 +299,16 @@ class DistributedEngine:
                 lambda Pa: join_with_col0(backend, Pa, I, F)
             )
             Jf, Jb, col0p = join_b(P_all)                 # (B_loc, c, ℓp) ×2
-            f = P_local.shape[1]
-            start = linear_index(c_axes) * f
-            Jf_loc = jax.lax.dynamic_slice_in_dim(Jf, start, f, 1)
-            Jb_loc = jax.lax.dynamic_slice_in_dim(Jb, start, f, 1)
+            Jf_loc, Jb_loc = _own_entries(Jf, Jb, c_axes, P_local.shape[1], axis=1)
             bm_b = backend.lift_batch(
                 lambda ch, ef, eb: backend.build_merge_packed(N, ch, ef, eb)
             )
             M = bm_b(batch, Jf_loc, Jb_loc)               # (B_loc, c_loc, k, W)
             return col0p, M
 
-        program = jax.shard_map(
-            body,
-            mesh=self.mesh,
-            check_vma=False,
-            in_specs=(PartitionSpec(), PartitionSpec(), PartitionSpec(), spec_in),
-            out_specs=(spec_b, spec_in),
-        )
-        rep = self._rep()
-        return jax.jit(
-            program,
-            in_shardings=(rep, rep, rep, NamedSharding(self.mesh, spec_in)),
-            out_shardings=(
-                NamedSharding(self.mesh, spec_b),
-                NamedSharding(self.mesh, spec_in),
-            ),
-        )
+        return self._program(body, (_REP, _REP, _REP, spec_in), (spec_b, spec_in))
 
     # ------------------------------------------------- streaming join route
-
-    @property
-    def join_program(self):
-        if self._join_prog is None:
-            self._join_prog = self._build_join_program()
-        return self._join_prog
-
-    def _build_join_program(self):
-        backend = self.engine.backend
-        axes = self.chunk_axes
-        spec = PartitionSpec(_entry(axes))
-
-        def body(P, I, F):  # P: (f, ℓp, ℓp) shard-local product rows
-            self._bump()
-            P_all = _gather(P, axes, axis=0)
-            return join_with_col0(backend, P_all, I, F)
-
-        program = jax.shard_map(
-            body,
-            mesh=self.mesh,
-            check_vma=False,
-            in_specs=(spec, PartitionSpec(), PartitionSpec()),
-            out_specs=(PartitionSpec(), PartitionSpec(), PartitionSpec()),
-        )
-        rep = self._rep()
-        return jax.jit(
-            program,
-            in_shardings=(NamedSharding(self.mesh, spec), rep, rep),
-            out_shardings=(rep, rep, rep),
-        )
 
     def join_products(self, P: jnp.ndarray):
         """Sharded-stack join — the streaming contract.
@@ -324,25 +335,73 @@ class DistributedEngine:
 
     # ---------------------------------------------------------------- parse
 
-    def parse(self, text, n_chunks: Optional[int] = None) -> SLPF:
-        """One text, the chunk dim sharded over EVERY chunk axis.
+    def _put_text(self, text, n_chunks: Optional[int]):
+        """Bucket one text for the single-text route and place its padded
+        chunks over the chunk axes (span ``parse.host_prep``).
 
-        The long-text route: one device program, reach/build&merge shard-
-        local, one product-stack all-gather.  ``n_chunks`` rounds up to a
-        multiple of the chunk device count (default: one bucket-padded chunk
-        row per device, at least 8 rows total).
-        """
+        ``n_chunks`` rounds up to a multiple of the chunk device count
+        (default: one bucket-padded chunk row per device, at least 8 rows
+        total).  Returns (classes, (c, k), sharded chunks)."""
         eng = self.engine
         csz = self.chunk_devices
         c_req = n_chunks if n_chunks is not None else max(8, csz)
         c_req = _round_up(max(1, c_req), csz)
         classes = eng.classes_of_text(text)
         c, k = eng.bucket_shape(len(classes), c_req)
-        chunks = eng._pad_to(classes, c, k)
-        t = eng.tables
+        cells, text_cells = c * k, len(classes)
+        with eng.obs.span(
+            "parse.host_prep", bucket=[c, k], cells=cells, text_cells=text_cells
+        ):
+            placed = self._named(self._chunk_spec)
+            chunks = jax.block_until_ready(
+                jax.device_put(eng._pad_to(classes, c, k), placed)
+            )
+        eng._count_cells(c, k, cells, text_cells)
+        return classes, (c, k), chunks
+
+    def parse(self, text, n_chunks: Optional[int] = None) -> SLPF:
+        """One text, the chunk dim sharded over EVERY chunk axis.
+
+        The long-text route: one device program, reach/build&merge shard-
+        local, one product-stack all-gather (spans ``parse.host_prep``,
+        ``parse.device``, then the engine's ``parse.fetch`` /
+        ``parse.assemble``).
+        """
+        eng, t = self.engine, self.engine.tables
+        classes, (c, k), chunks = self._put_text(text, n_chunks)
         self._count_allgather(c, self.chunk_axes)
-        col0, cols = jax.block_until_ready(self.chunk_program(t.N, t.I, t.F, chunks))
+        with eng.obs.span(
+            "parse.device", bucket=[c, k], batch=1, chips=self.chunk_devices
+        ):
+            col0, cols = jax.block_until_ready(
+                self.chunk_program(t.N, t.I, t.F, chunks)
+            )
         return eng.fetch_assemble((col0,), (cols,), [classes])[0]
+
+    def parse_phase_split(self, text, n_chunks: Optional[int] = None) -> SLPF:
+        """``parse`` as three sharded programs with host seams, each phase
+        span blocked on its result: ``phase.reach`` (shard-local products),
+        ``phase.join`` (the all-gather and the replicated join; attributes
+        ``gather_bytes`` and ``chips``), ``phase.build_merge`` (shard-local)
+        and ``phase.host_build`` (fetch and assembly).  The same body pieces
+        as ``chunk_program``, so bit-identical to ``parse``."""
+        eng, t = self.engine, self.engine.tables
+        obs = eng.obs
+        classes, (c, k), chunks = self._put_text(text, n_chunks)
+        with obs.span("phase.reach", bucket=[c, k], n_chars=len(classes)):
+            P = jax.block_until_ready(self.reach_program(t.N, chunks))
+        gather_bytes = self._count_allgather(c, self.chunk_axes)
+        with obs.span(
+            "phase.join", n_products=c, gather_bytes=gather_bytes,
+            chips=self.chunk_devices,
+        ):
+            Jf, Jb, col0p = jax.block_until_ready(self.join_program(P, t.I, t.F))
+        with obs.span("phase.build_merge", bucket=[c, k]):
+            cols = jax.block_until_ready(
+                self.build_merge_program(t.N, chunks, Jf, Jb)
+            )
+        with obs.span("phase.host_build", n_chars=len(classes)):
+            return eng.fetch_assemble((col0p,), (cols,), [classes])[0]
 
     def parse_batch(self, texts: Sequence, n_chunks: int = 8) -> List[SLPF]:
         """Many texts: batch slots over 'data' × chunks over 'pod'.
@@ -350,9 +409,11 @@ class DistributedEngine:
         Identical grouping/bucketing to ``ParserEngine.parse_batch``; batch
         slots additionally round up to a multiple of the batch device count
         and chunk counts to the chunk device count (all-PAD rows/chunks are
-        identity, discarded on assembly).
+        identity, discarded on assembly).  Spans and cell counters as on
+        the single-device route.
         """
         eng = self.engine
+        obs = eng.obs
         csz = self.batch_chunk_devices
         dsz = self.batch_devices
         c_req = _round_up(max(1, n_chunks), csz)
@@ -362,17 +423,29 @@ class DistributedEngine:
             groups.setdefault(eng.bucket_shape(len(cls), c_req), []).append(i)
 
         t = eng.tables
+        spec_in = PartitionSpec(_entry(self.batch_axes), _entry(self.batch_chunk_axes))
         results: List[Optional[SLPF]] = [None] * len(texts)
         for (c, k), idxs in sorted(groups.items()):
             B = _round_up(_next_pow2(len(idxs)), dsz)
-            batch = np.full((B, c, k), t.pad_class, dtype=np.int32)
-            for row, i in enumerate(idxs):
-                batch[row] = eng._pad_to(classes_list[i], c, k)
-            self._count_allgather(B * c, self.batch_chunk_axes)
-            col0s, colss = jax.block_until_ready(
-                self.batched_program(t.N, t.I, t.F, batch)
-            )
             group = [classes_list[i] for i in idxs]
+            cells, text_cells = B * c * k, sum(len(cls) for cls in group)
+            with obs.span(
+                "parse.host_prep", bucket=[c, k], cells=cells, text_cells=text_cells
+            ):
+                batch = np.full((B, c, k), t.pad_class, dtype=np.int32)
+                for row, cls in enumerate(group):
+                    batch[row] = eng._pad_to(cls, c, k)
+                batch = jax.block_until_ready(
+                    jax.device_put(batch, self._named(spec_in))
+                )
+            eng._count_cells(c, k, cells, text_cells)
+            self._count_allgather(B * c, self.batch_chunk_axes)
+            with obs.span(
+                "parse.device", bucket=[c, k], batch=B, chips=dsz * csz
+            ):
+                col0s, colss = jax.block_until_ready(
+                    self.batched_program(t.N, t.I, t.F, batch)
+                )
             for i, slpf in zip(idxs, eng.fetch_assemble(col0s, colss, group)):
                 results[i] = slpf
         return results  # type: ignore[return-value]

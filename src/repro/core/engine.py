@@ -408,9 +408,7 @@ class ParserEngine:
                 for row, cls in enumerate(group):
                     batch[row] = self._pad_to(cls, c, k)
                 batch = jnp.asarray(batch)
-            padded_total, text_total = self._cell_counters(c, k)
-            padded_total.inc(cells - text_cells)
-            text_total.inc(text_cells)
+            self._count_cells(c, k, cells, text_cells)
             with obs.span("parse.device", bucket=[c, k], batch=B):
                 col0s, colss = jax.block_until_ready(
                     self._jit_batched(t.N, t.I, t.F, batch)
@@ -419,8 +417,9 @@ class ParserEngine:
                 results[i] = slpf
         return results  # type: ignore[return-value]
 
-    def _cell_counters(self, c: int, k: int) -> Tuple[Counter, Counter]:
-        """``padded_cells_total`` / ``text_cells_total`` of one bucket."""
+    def _count_cells(self, c: int, k: int, cells: int, text_cells: int) -> None:
+        """Add one dispatch's cells to ``padded_cells_total`` /
+        ``text_cells_total`` of its (c, k) bucket."""
         handles = self._m_cells.get((c, k))
         if handles is None:
             m, label = self.obs.metrics, f"{c}x{k}"
@@ -428,7 +427,9 @@ class ParserEngine:
                 m.counter("padded_cells_total", bucket=label),
                 m.counter("text_cells_total", bucket=label),
             )
-        return handles
+        padded_total, text_total = handles
+        padded_total.inc(cells - text_cells)
+        text_total.inc(text_cells)
 
     def fetch_assemble(self, col0s, colss, classes_list) -> List[SLPF]:
         """Copy one program's packed columns to the host (``parse.fetch``)
@@ -486,16 +487,16 @@ class ParserEngine:
         ``tests/test_conformance.py``) — so each phase boundary is a real
         host-side seam that can be timed honestly: every span blocks on its
         device result before closing.  Queue-free: this is the direct route
-        ``Parser.parse`` takes when tracing is enabled (mesh engines keep
-        their fused distributed program and report one ``phase.device_parse``
-        span instead — the phases live inside one ``shard_map``).
+        ``Parser.parse`` takes when tracing is enabled.  A mesh engine runs
+        the same phases as sharded programs
+        (``DistributedEngine.parse_phase_split``): ``phase.join`` then
+        holds the product all-gather, and ``phase.host_build`` the fetch
+        from every device and the assembly.
         """
+        if self.mesh is not None:
+            return self.dist.parse_phase_split(text, n_chunks=n_chunks)
         obs = self.obs
         classes = self.classes_of_text(text)
-        if self.mesh is not None:
-            with obs.span("phase.device_parse", n_chars=len(classes)):
-                slpf = self.dist.parse(classes, n_chunks=n_chunks)
-            return slpf
         c, k = self.bucket_shape(len(classes), n_chunks)
         chunks = jnp.asarray(self._pad_to(classes, c, k))
         t = self.tables

@@ -42,6 +42,7 @@ Span taxonomy (documented in ROADMAP "Observability"):
     parse.host_prep        pad into the (B, c, k) bucket, host → device
                            (attrs ``cells`` = B·c·k, ``text_cells``)
     parse.device           the fused program, blocked on its results
+                           (attr ``chips`` on a mesh's routes)
     parse.fetch            device → host copy of the packed columns (``bytes``)
     parse.assemble         host SLPF assembly of every text of the batch
   parse.speculation        observed speculation widths (``Parser._wrap``),
@@ -60,8 +61,11 @@ its ``parse.request``:
   phase.join               exclusive scan over stacked products (device)
   phase.build_merge        builder&merger over join entries (device)
   phase.host_build         host-side SLPF assembly (unpack + wrap)
-  phase.device_parse       a mesh's one sharded program (phases not split),
-                           then ``parse.fetch`` / ``parse.assemble``
+
+On a mesh the same phases run as sharded programs, after ``parse.host_prep``
+(the sharded host → device put): ``phase.join`` holds the product
+all-gather (attrs ``gather_bytes``, ``chips``) and ``phase.host_build``
+the engine's ``parse.fetch`` / ``parse.assemble`` over every device.
 """
 
 from __future__ import annotations

@@ -158,3 +158,32 @@ def test_mesh_programs_compile_on_four_chips(topo):
         assert "all-gather" in compiled.as_text()
         assert len(compiled.input_shardings[0][3].device_set) == 4
         _fits(compiled)
+
+
+def test_mesh_phase_split_programs_compile_on_four_chips(topo):
+    """The traced mesh route at the four-chip cell's bucket (32 chunks of
+    262,144 over a (2, 2) mesh): reach and build&merge shard-local, the
+    join program the only one with the product all-gather."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("pod", "data"))
+    eng = ParserEngine(compute_segments(TRAFFIC_RE), backend="sparse", mesh=mesh)
+    t, dist = eng.tables, eng.dist
+    rep = NamedSharding(mesh, PartitionSpec())
+    rows = NamedSharding(mesh, PartitionSpec(("pod", "data")))
+
+    def spec(a, sharding):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+    N, I, F = (spec(a, rep) for a in (t.N, t.I, t.F))
+    chunks = jax.ShapeDtypeStruct((32, 262_144), jnp.int32, sharding=rows)
+    P = spec(jax.eval_shape(dist.reach_program, N, chunks), rows)
+    Jf, Jb, _ = (spec(a, rep) for a in jax.eval_shape(dist.join_program, P, I, F))
+    programs = [
+        (dist.reach_program, (N, chunks), False),
+        (dist.join_program, (P, I, F), True),
+        (dist.build_merge_program, (N, chunks, Jf, Jb), False),
+    ]
+    for prog, args, gathers in programs:
+        compiled = prog.lower(*args).compile()
+        assert ("all-gather" in compiled.as_text()) == gathers
+        assert len(compiled.input_shardings[0][0].device_set) == 4
+        _fits(compiled)

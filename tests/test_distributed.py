@@ -243,13 +243,83 @@ for piece in ["ab", "abab", "ba"*4]:
     assert sp.accepted == cold.accepted
 print("DISTRIBUTED-OK")
 """
-    env = {"PYTHONPATH": str(Path(__file__).parents[1] / "src"), "PATH": "/usr/bin:/bin"}
+    _run_fresh(code, "DISTRIBUTED-OK", timeout=600)
+
+
+def _run_fresh(code: str, marker: str, timeout: int) -> None:
+    """Run ``code`` in a fresh interpreter (it forces its own device count)
+    and check that it printed ``marker``."""
     import os
 
+    env = {"PYTHONPATH": str(Path(__file__).parents[1] / "src"), "PATH": "/usr/bin:/bin"}
     env.update({k: v for k, v in os.environ.items() if k not in env})
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True, env=env, timeout=600
+        [sys.executable, "-c", code], capture_output=True, text=True, env=env,
+        timeout=timeout,
     )
     assert out.returncode == 0, out.stderr[-2000:]
-    assert "DISTRIBUTED-OK" in out.stdout
+    assert marker in out.stdout
+
+
+TRAFFIC_RE = r"((GET|POST|PUT) /([a-z0-9]|/)* ([0-9]{3}) (ok|err|-)\n)+"
+
+
+def test_traffic_mesh_routes_4dev_match_serial_and_one_device():
+    """The four-chip TRAFFIC deployment at CPU size: ``mesh="host"`` over
+    four virtual devices ((2, 2) pod×data, 32 chunks, 8 rows per device).
+    The fused mesh route and the mesh phase-split route give the serial
+    parser's columns and a single-device parser's, and the join's
+    ``gather_bytes`` is what the all-gather counter adds."""
+    code = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, numpy as np, repro
+from repro.core.serial import parse_serial_matrix
+assert len(jax.devices()) == 4
+RE = %r
+cfg = dict(regex=RE, backend="auto", n_chunks=32)
+mesh = repro.Parser(repro.ParserConfig(mesh="host", **cfg))
+split = repro.Parser(repro.ParserConfig(mesh="host", obs={"enabled": True}, **cfg))
+one = repro.Parser(repro.ParserConfig(**cfg))
+assert mesh.backend_name == "sparse" and mesh.engine.dist.chunk_devices == 4
+assert dict(mesh.engine.mesh.shape) == {"pod": 2, "data": 2}
+
+def record(rng):
+    path = "".join(rng.choice(list("abcdefghijklmnopqrstuvwxyz0123456789/"),
+                              size=int(rng.integers(0, 7))))
+    return "%%s /%%s %%03d %%s\n" %% (rng.choice(["GET", "POST", "PUT"]), path,
+                                 int(rng.integers(0, 1000)),
+                                 rng.choice(["ok", "err", "-"]))
+
+def counter(p):
+    snap = p.stats()["metrics"].get("allgather_payload_bytes_total", [])
+    return sum(s["value"] for s in snap)
+
+rng = np.random.default_rng(2503)
+texts = []
+for size in (2000, 3500, 5000):
+    t = ""
+    while len(t) < size:
+        t += record(rng)
+    texts.append(t)
+texts.append(texts[0][:-3])              # cut mid-record: not accepted
+for t in texts:
+    want = parse_serial_matrix(one.engine.matrices, t).columns
+    single = one.parse(t).forest.columns
+    fused = mesh.parse(t).forest.columns
+    before = counter(split)
+    r = split.parse(t)
+    assert np.array_equal(single, want), len(t)
+    assert np.array_equal(fused, want), len(t)
+    assert np.array_equal(r.forest.columns, want), len(t)
+    assert r.ok == bool(want[-1].any())
+    spans = {s.name: s for s in split.obs.tracer.drain() if s.trace_id == r.trace_id}
+    assert "phase.device_parse" not in spans
+    join = spans["phase.join"].attrs
+    assert join["chips"] == 4 and join["n_products"] == 32
+    assert join["gather_bytes"] == counter(split) - before
+    assert join["gather_bytes"] == 32 * split.engine.dist._product_nbytes()
+print("TRAFFIC-MESH4-OK")
+""" % TRAFFIC_RE
+    _run_fresh(code, "TRAFFIC-MESH4-OK", timeout=300)

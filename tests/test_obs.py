@@ -352,21 +352,73 @@ def test_parse_phase_split_emits_phase_spans(traced_parser):
     assert np.array_equal(split.pack(), p.submit(text).result().forest.pack())
 
 
+MESH_PHASES = ["parse.host_prep", "phase.reach", "phase.join",
+               "phase.build_merge", "phase.host_build"]
+
+
 def test_mesh_direct_route_spans():
-    # a (1, 1) mesh on the plain suite's one device: the sharded programs
-    # run without collectives, through the same spans as a real mesh
+    # a (1, 1) mesh on the plain suite's one device: the sharded phase
+    # programs run without collectives, through the same spans as a real mesh
     p = repro.Parser(repro.ParserConfig(
         regex=PATTERN, n_chunks=4, mesh="host", obs={"enabled": True},
     ))
-    r = p.parse("ab" * 12)
+    plain = repro.Parser(repro.ParserConfig(regex=PATTERN, n_chunks=4))
+    text = "ab" * 12
+    r = p.parse(text)
     spans = [s.to_dict() for s in p.obs.tracer.drain()]
     tree = validate_span_tree(spans, r.trace_id)
     root = tree["root"]
     direct = [s["name"] for s in _children(spans, root)]
-    assert direct == ["phase.device_parse", "parse.speculation"]
-    device = _children(spans, root)[0]
-    assert [s["name"] for s in _children(spans, device)] == [
+    assert direct == MESH_PHASES + ["parse.speculation"]
+    host_build = _children(spans, root)[MESH_PHASES.index("phase.host_build")]
+    assert [s["name"] for s in _children(spans, host_build)] == [
         "parse.fetch", "parse.assemble"]
+    assert "phase.device_parse" not in {s["name"] for s in spans}
+    assert np.array_equal(r.forest.pack(), plain.parse(text).forest.pack())
+    plain.close()
+    p.close()
+
+
+@pytest.mark.parametrize("route", ["parse", "parse_batch"])
+def test_mesh_fused_route_spans_carry_cells_and_chips(route):
+    p = repro.Parser(repro.ParserConfig(
+        regex=PATTERN, n_chunks=4, mesh="host", obs={"enabled": True},
+    ))
+    eng = p.engine
+    texts = ["ab" * 9 + "a", "ba" * 5]
+    if route == "parse":
+        texts = texts[:1]
+        eng.parse(texts[0], n_chunks=4)
+    else:
+        eng.parse_batch(texts, n_chunks=4)
+    n = [len(t) for t in texts]
+    c, k = eng.bucket_shape(max(n), 4)
+    B = 1 if route == "parse" else 2
+    spans = {s.name: s for s in p.obs.tracer.drain()}
+    assert list(spans) == ["parse.host_prep", "parse.device", "parse.fetch",
+                           "parse.assemble"]
+    prep, device = spans["parse.host_prep"], spans["parse.device"]
+    assert prep.attrs == {"bucket": [c, k], "cells": B * c * k,
+                          "text_cells": sum(n)}
+    assert device.attrs == {"bucket": [c, k], "batch": B, "chips": 1}
+    label = f"{c}x{k}"
+    assert _value(p, "text_cells_total", bucket=label) == sum(n)
+    assert _value(p, "padded_cells_total", bucket=label) == B * c * k - sum(n)
+    p.close()
+
+
+def test_mesh_join_gather_bytes_match_the_counter():
+    p = repro.Parser(repro.ParserConfig(
+        regex=PATTERN, n_chunks=4, mesh="host", obs={"enabled": True},
+    ))
+    dist = p.engine.dist
+    before = _value(p, "allgather_payload_bytes_total")
+    p.parse("ab" * 21)
+    join = [s for s in p.obs.tracer.drain() if s.name == "phase.join"]
+    assert len(join) == 1
+    moved = _value(p, "allgather_payload_bytes_total") - before
+    assert join[0].attrs["gather_bytes"] == moved == 4 * dist._product_nbytes()
+    assert join[0].attrs["chips"] == 1 and join[0].attrs["n_products"] == 4
     p.close()
 
 
