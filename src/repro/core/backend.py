@@ -68,6 +68,20 @@ bucket reaches ℓp (an automaton whose first characters admit ~all states),
 S = ℓp — the representation degenerates to dense packed rows plus an index
 column and every op stays correct, just without the reduction.
 
+Long chunks as sub-chains: a chunk's reach and build&merge are chains of k
+dependent steps, so on the chip step latency, not work, sets their time.
+The packed and sparse backends cut every chunk of static length
+k ≥ ``SPLIT_MIN_LEN`` into m = k / ``SUB_CHAIN_LEN`` contiguous sub-chains
+(``sub_chains(k)``).  ``reach`` folds all c·m sub-chains in one scan of
+k/m steps, then composes each chunk's m sub-products in a sequential
+m-step scan; ``build_merge_packed`` recomputes the sub-products, carries
+each chunk's entry pair across its sub-chains (a forward scan of ``act``
+and a reverse scan of ``act_T``, m steps each), and builds every sub-chain
+as a chunk of its own.  Every step is one ``lax.scan``, so the program
+has the same loops whatever m is; the outputs, and so the join's c
+products and the mesh's all-gather, are bit-identical to the whole-chunk
+programs, which shorter chunks keep unchanged.
+
 The non-product boundaries are fixed across backends: ``join`` consumes a
 (c, …) product stack and returns f32 (c, ℓp) entry vectors {0,1};
 ``start_column`` returns the f32 (ℓp,) text-start column; and
@@ -107,6 +121,14 @@ from .scan import exclusive_entries
 
 def _next_pow2(n: int) -> int:
     return 1 << max(0, (int(n) - 1).bit_length())
+
+
+#: Chunks at least this long run reach and build&merge as sub-chains of
+#: ``SUB_CHAIN_LEN`` steps (``PackedBackend.sub_chains``); shorter chunks
+#: keep the whole-chunk program.
+SPLIT_MIN_LEN = 1 << 14
+#: Steps of one sub-chain of a split chunk.
+SUB_CHAIN_LEN = 1 << 9
 
 
 def _resolve_interpret(interpret: Union[bool, None]) -> bool:
@@ -258,6 +280,11 @@ class ParserBackend:
         here; the default only checks ℓp against the kernels' limit.
         """
         self.check_ell_pad(int(tables.ell_pad))
+
+    def sub_chains(self, k: int) -> int:
+        """Sub-chains m that ``reach`` and ``build_merge_packed`` cut each
+        chunk of static length ``k`` into (1: the chunk runs whole)."""
+        return 1
 
     def reach(self, N: jnp.ndarray, chunks: jnp.ndarray) -> jnp.ndarray:
         """(c, k) chunks → stacked chunk products (axis 0 = chunk)."""
@@ -441,15 +468,24 @@ class PackedBackend(ParserBackend):
 
         return MAX_ELL_PAD
 
-    def reach(self, N, chunks):
-        Np = pack_transition_table_jnp(N)            # (A+1, ℓp, W)
+    def sub_chains(self, k: int) -> int:
+        """k / ``SUB_CHAIN_LEN`` once a chunk reaches ``SPLIT_MIN_LEN``
+        steps, else 1.  A kernel keeps whole chunks: it owns the
+        intra-chunk grid."""
+        if self.kernel or k < SPLIT_MIN_LEN or k % SUB_CHAIN_LEN:
+            return 1
+        return k // SUB_CHAIN_LEN
+
+    def chain_products(self, N, Np, chains):
+        """(n, L) chains → n products, each folded from the chain's start:
+        the whole-chunk reach body, also run over sub-chains."""
         if self.kernel:
             from ..kernels.packed_reach import packed_reach_chunk_product
 
             interp = _resolve_interpret(self.interpret)
             return jax.lax.map(
                 lambda ch: packed_reach_chunk_product(Np, ch, interpret=interp),
-                chunks,
+                chains,
             )
         eye = packed_identity(N.shape[-1])
 
@@ -460,34 +496,86 @@ class PackedBackend(ParserBackend):
             Q, _ = jax.lax.scan(step, eye, chunk)
             return Q
 
-        return jax.vmap(one)(chunks)
+        return jax.vmap(one)(chains)
+
+    def _sub_products(self, N, Np, chunks, m):
+        """(c, k) chunks → (c, m, …) products of their m contiguous
+        sub-chains, all c·m folded in one ``L = k/m``-step scan."""
+        c, k = chunks.shape
+        P = self.chain_products(N, Np, chunks.reshape(c * m, k // m))
+        return P.reshape((c, m) + P.shape[1:])
+
+    def reach(self, N, chunks):
+        Np = pack_transition_table_jnp(N)            # (A+1, ℓp, W)
+        m = self.sub_chains(chunks.shape[-1])
+        if m == 1:
+            return self.chain_products(N, Np, chunks)
+
+        def fold(subs):                              # (m, …) → chunk product
+            def step(acc, p):
+                return self.compose(p, acc), None
+
+            acc, _ = jax.lax.scan(step, subs[0], subs[1:])
+            return acc
+
+        return jax.vmap(fold)(self._sub_products(N, Np, chunks, m))
 
     def compose(self, later, earlier):
         return packed_semiring_matmul(later, earlier)
+
+    def act(self, P, v):
+        """``P v`` on f32 {0,1} state vectors (the join's forward act)."""
+        return packed_matvec(P, v)
+
+    def act_T(self, P, v):
+        """``Pᵀ v`` on f32 {0,1} state vectors (the backward act)."""
+        return packed_matvec_T(P, v)                 # transpose is free packed
 
     def identity_product(self, ell_pad, dtype=jnp.float32):
         return packed_identity(ell_pad)
 
     def join(self, P, I, F):
         Jf = exclusive_entries(
-            combine=packed_semiring_matmul,
-            act=packed_matvec,
-            summaries=P,
-            init=I,
+            combine=self.compose, act=self.act, summaries=P, init=I
         )
         Jb_rev = exclusive_entries(
-            combine=lambda later, earlier: packed_semiring_matmul(earlier, later),
-            act=packed_matvec_T,                     # transpose is free packed
+            combine=lambda later, earlier: self.compose(earlier, later),
+            act=self.act_T,
             summaries=P[::-1],
             init=F,
         )
         return Jf, Jb_rev[::-1]
 
     def start_column(self, P, I, Jb0):
-        return I * packed_matvec_T(P[0], Jb0)
+        return I * self.act_T(P[0], Jb0)
+
+    def _sub_entries(self, subs, ef, eb):
+        """One chunk's (m, …) sub-products and entry pair → the m sub-chains'
+        forward and backward entries, (m, ℓp) each: sub-chain j enters with
+        ``subs[j-1] ⊗ … ⊗ subs[0]`` applied to ``ef`` and with
+        ``(subs[m-1] ⊗ … ⊗ subs[j+1])ᵀ`` applied to ``eb``."""
+
+        def fstep(v, p):
+            return self.act(p, v), v
+
+        def bstep(v, p):
+            return self.act_T(p, v), v
+
+        _, ef_subs = jax.lax.scan(fstep, ef, subs)
+        _, eb_subs = jax.lax.scan(bstep, eb, subs, reverse=True)
+        return ef_subs, eb_subs
 
     def build_merge_packed(self, N, chunks, Jf, Jb):
         Np = pack_transition_table_jnp(N)
+        c, k = chunks.shape
+        m = self.sub_chains(k)
+        if m > 1:
+            # recompute the sub-products, carry each chunk's entries across
+            # its sub-chains, then build every sub-chain as a chunk of its own
+            subs = self._sub_products(N, Np, chunks, m)
+            Jf, Jb = jax.vmap(self._sub_entries)(subs, Jf, Jb)
+            chunks = chunks.reshape(c * m, k // m)
+            Jf, Jb = Jf.reshape(c * m, -1), Jb.reshape(c * m, -1)
 
         def one(chunk, ef, eb):
             def fstep(vp, cls):
@@ -508,7 +596,8 @@ class PackedBackend(ParserBackend):
             _, M = jax.lax.scan(bstep, pack_bits_jnp(eb), (chunk, fwd), reverse=True)
             return M                                 # (k, W) packed columns
 
-        return jax.vmap(one)(chunks, Jf, Jb)
+        # sub-chains are contiguous: (c·m, L, W) is (c, k, W) row for row
+        return jax.vmap(one)(chunks, Jf, Jb).reshape(c, k, -1)
 
     def build_merge(self, N, chunks, Jf, Jb):
         from .matrices import unpack_bits_jnp
@@ -622,12 +711,11 @@ class SparseBackend(PackedBackend):
 
     # ------------------------------------------------------------ phase ops
 
-    def reach(self, N, chunks):
+    def chain_products(self, N, Np, chains):
         lp = N.shape[-1]
         S = self._require_bound(lp)
-        Np = pack_transition_table_jnp(N)            # (A+1, ℓp, W)
         pad_cls = N.shape[0] - 1
-        depth = min(self.depth, chunks.shape[-1])
+        depth = min(self.depth, chains.shape[-1])
         ident = sparse_identity(S, lp // 32)
         if self.kernel:
             from ..kernels.packed_reach import packed_fold_rows
@@ -660,39 +748,27 @@ class SparseBackend(PackedBackend):
 
                 R, _ = jax.lax.scan(step, R0, chunk)
             body = jnp.concatenate([idx.astype(jnp.uint32)[:, None], R], axis=1)
-            # all-PAD padding chunk ⇔ first class is PAD (PAD only pads the
-            # tail) ⇒ product is exactly the identity → flagged encoding
+            # a chain that starts with PAD is all PAD (PAD only pads the
+            # tail) ⇒ its product is exactly the identity → flagged encoding
             return jnp.where(chunk[0] == pad_cls, ident, body)
 
         if self.kernel:
             # sequential over chunks: the kernel owns the intra-chunk grid
-            return jax.lax.map(one, chunks)
-        return jax.vmap(one)(chunks)
+            return jax.lax.map(one, chains)
+        return jax.vmap(one)(chains)
 
     def compose(self, later, earlier):
         return sparse_compose(later, earlier)
 
+    def act(self, P, v):
+        return sparse_matvec(P, v)
+
+    def act_T(self, P, v):
+        return sparse_matvec_T(P, v)                 # transpose free on rows
+
     def identity_product(self, ell_pad, dtype=jnp.float32):
         S = self._require_bound(ell_pad)
         return sparse_identity(S, ell_pad // 32)
-
-    def join(self, P, I, F):
-        Jf = exclusive_entries(
-            combine=sparse_compose,
-            act=sparse_matvec,
-            summaries=P,
-            init=I,
-        )
-        Jb_rev = exclusive_entries(
-            combine=lambda later, earlier: sparse_compose(earlier, later),
-            act=sparse_matvec_T,                     # transpose free on rows
-            summaries=P[::-1],
-            init=F,
-        )
-        return Jf, Jb_rev[::-1]
-
-    def start_column(self, P, I, Jb0):
-        return I * sparse_matvec_T(P[0], Jb0)
 
 
 _BACKENDS: Dict[str, Type[ParserBackend]] = {}

@@ -371,7 +371,8 @@ class DistributedEngine:
         classes, (c, k), chunks = self._put_text(text, n_chunks)
         self._count_allgather(c, self.chunk_axes)
         with eng.obs.span(
-            "parse.device", bucket=[c, k], batch=1, chips=self.chunk_devices
+            "parse.device", bucket=[c, k], batch=1, chips=self.chunk_devices,
+            sub_chains=eng.backend.sub_chains(k),
         ):
             col0, cols = jax.block_until_ready(
                 self.chunk_program(t.N, t.I, t.F, chunks)
@@ -388,7 +389,10 @@ class DistributedEngine:
         eng, t = self.engine, self.engine.tables
         obs = eng.obs
         classes, (c, k), chunks = self._put_text(text, n_chunks)
-        with obs.span("phase.reach", bucket=[c, k], n_chars=len(classes)):
+        m = eng.backend.sub_chains(k)
+        with obs.span(
+            "phase.reach", bucket=[c, k], n_chars=len(classes), sub_chains=m
+        ):
             P = jax.block_until_ready(self.reach_program(t.N, chunks))
         gather_bytes = self._count_allgather(c, self.chunk_axes)
         with obs.span(
@@ -396,7 +400,7 @@ class DistributedEngine:
             chips=self.chunk_devices,
         ):
             Jf, Jb, col0p = jax.block_until_ready(self.join_program(P, t.I, t.F))
-        with obs.span("phase.build_merge", bucket=[c, k]):
+        with obs.span("phase.build_merge", bucket=[c, k], sub_chains=m):
             cols = jax.block_until_ready(
                 self.build_merge_program(t.N, chunks, Jf, Jb)
             )
@@ -441,7 +445,8 @@ class DistributedEngine:
             eng._count_cells(c, k, cells, text_cells)
             self._count_allgather(B * c, self.batch_chunk_axes)
             with obs.span(
-                "parse.device", bucket=[c, k], batch=B, chips=dsz * csz
+                "parse.device", bucket=[c, k], batch=B, chips=dsz * csz,
+                sub_chains=eng.backend.sub_chains(k),
             ):
                 col0s, colss = jax.block_until_ready(
                     self.batched_program(t.N, t.I, t.F, batch)

@@ -409,7 +409,10 @@ class ParserEngine:
                     batch[row] = self._pad_to(cls, c, k)
                 batch = jnp.asarray(batch)
             self._count_cells(c, k, cells, text_cells)
-            with obs.span("parse.device", bucket=[c, k], batch=B):
+            with obs.span(
+                "parse.device", bucket=[c, k], batch=B,
+                sub_chains=self.backend.sub_chains(k),
+            ):
                 col0s, colss = jax.block_until_ready(
                     self._jit_batched(t.N, t.I, t.F, batch)
                 )
@@ -419,17 +422,22 @@ class ParserEngine:
 
     def _count_cells(self, c: int, k: int, cells: int, text_cells: int) -> None:
         """Add one dispatch's cells to ``padded_cells_total`` /
-        ``text_cells_total`` of its (c, k) bucket."""
+        ``text_cells_total`` of its (c, k) bucket, and the dispatch to
+        ``split_dispatches_total`` when its program runs sub-chains."""
         handles = self._m_cells.get((c, k))
         if handles is None:
             m, label = self.obs.metrics, f"{c}x{k}"
             handles = self._m_cells[(c, k)] = (
                 m.counter("padded_cells_total", bucket=label),
                 m.counter("text_cells_total", bucket=label),
+                m.counter("split_dispatches_total", bucket=label)
+                if self.backend.sub_chains(k) > 1 else None,
             )
-        padded_total, text_total = handles
+        padded_total, text_total, split_total = handles
         padded_total.inc(cells - text_cells)
         text_total.inc(text_cells)
+        if split_total is not None:
+            split_total.inc()
 
     def fetch_assemble(self, col0s, colss, classes_list) -> List[SLPF]:
         """Copy one program's packed columns to the host (``parse.fetch``)
@@ -500,11 +508,14 @@ class ParserEngine:
         c, k = self.bucket_shape(len(classes), n_chunks)
         chunks = jnp.asarray(self._pad_to(classes, c, k))
         t = self.tables
-        with obs.span("phase.reach", bucket=[c, k], n_chars=len(classes)):
+        m = self.backend.sub_chains(k)
+        with obs.span(
+            "phase.reach", bucket=[c, k], n_chars=len(classes), sub_chains=m
+        ):
             P = jax.block_until_ready(self.phases.reach(t.N, chunks))
         with obs.span("phase.join", n_products=c):
             Jf, Jb, col0p = jax.block_until_ready(self.phases.join(P, t.I, t.F))
-        with obs.span("phase.build_merge", bucket=[c, k]):
+        with obs.span("phase.build_merge", bucket=[c, k], sub_chains=m):
             cols = jax.block_until_ready(
                 self.phases.build_merge(t.N, chunks, Jf, Jb)
             )

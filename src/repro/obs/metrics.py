@@ -71,6 +71,9 @@ METRIC_CATALOG: Dict[str, Tuple] = {
         "counter", "PAD cells the fused program ran (B·c·k less text), per bucket",
     ),
     "text_cells_total": ("counter", "text characters the fused program ran, per bucket"),
+    "split_dispatches_total": (
+        "counter", "dispatches whose program ran each chunk as sub-chains, per bucket",
+    ),
     "d2h_bytes_total": ("counter", "packed-column bytes copied device to host"),
     # fleet transition-table compile cache (core/fleet.py)
     "table_cache_hits_total": (

@@ -42,7 +42,8 @@ Span taxonomy (documented in ROADMAP "Observability"):
     parse.host_prep        pad into the (B, c, k) bucket, host → device
                            (attrs ``cells`` = B·c·k, ``text_cells``)
     parse.device           the fused program, blocked on its results
-                           (attr ``chips`` on a mesh's routes)
+                           (attr ``sub_chains``: the sub-chains m each chunk
+                           runs as, 1 when whole; ``chips`` on a mesh's routes)
     parse.fetch            device → host copy of the packed columns (``bytes``)
     parse.assemble         host SLPF assembly of every text of the batch
   parse.speculation        observed speculation widths (``Parser._wrap``),
@@ -57,9 +58,10 @@ A traced ``Parser.parse`` runs ``ParserEngine.parse_phase_split`` instead of
 the fused program, queue-free, and splits the device work by phase under
 its ``parse.request``:
 
-  phase.reach              chunk-product reach (device)
+  phase.reach              chunk-product reach (device; attr ``sub_chains``)
   phase.join               exclusive scan over stacked products (device)
-  phase.build_merge        builder&merger over join entries (device)
+  phase.build_merge        build&merge over join entries (device;
+                           attr ``sub_chains``)
   phase.host_build         host-side SLPF assembly (unpack + wrap)
 
 On a mesh the same phases run as sharded programs, after ``parse.host_prep``

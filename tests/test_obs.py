@@ -400,7 +400,8 @@ def test_mesh_fused_route_spans_carry_cells_and_chips(route):
     prep, device = spans["parse.host_prep"], spans["parse.device"]
     assert prep.attrs == {"bucket": [c, k], "cells": B * c * k,
                           "text_cells": sum(n)}
-    assert device.attrs == {"bucket": [c, k], "batch": B, "chips": 1}
+    assert device.attrs == {"bucket": [c, k], "batch": B, "chips": 1,
+                            "sub_chains": 1}
     label = f"{c}x{k}"
     assert _value(p, "text_cells_total", bucket=label) == sum(n)
     assert _value(p, "padded_cells_total", bucket=label) == B * c * k - sum(n)
